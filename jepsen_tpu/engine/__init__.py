@@ -20,8 +20,9 @@ stages the serial path used to run back-to-back:
 Verdicts are independent of the window size and bucketing — window=1
 is exactly the historical serial dispatch-sync-dispatch path (pinned
 by ``tests/test_engine.py`` and ``make pipeline-smoke``).  Pipeline
-occupancy, bubble time, in-flight depth, and bucket counts report
-through the ``obs`` metrics registry (doc/observability.md).
+bubble time, in-flight depth, and bucket counts report through the
+``obs`` metrics registry, and encode, plan, issue, dispatch and settle
+through ``engine/*`` spans (doc/observability.md).
 
 The engine is split into a pure per-run **planning** layer
 (:mod:`jepsen_tpu.engine.planning`: ``RunContext``, ``Planner``) and a

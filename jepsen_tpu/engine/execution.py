@@ -144,7 +144,7 @@ class DispatchWindow:
 
     A window is **owner-thread confined** (``# jt: guarded-by
     (owner-thread)`` on its state, checked by the lock-discipline lint
-    pass): the in-flight deque and bubble/peak bookkeeping are
+    pass): the in-flight deque and depth/count bookkeeping are
     deliberately lock-free, so ``submit``/``drain`` refuse calls from
     any thread but the creating one rather than corrupt them silently
     — the oracle worker pool must interact with the engine only
@@ -152,8 +152,10 @@ class DispatchWindow:
     driving the window.
 
     Time spent blocked in retirement is recorded as
-    ``jepsen_engine_bubble_seconds``; the post-submit depth feeds the
-    ``jepsen_engine_inflight_depth`` high-water gauge.
+    ``jepsen_engine_bubble_seconds`` and an ``engine/dispatch`` span;
+    the post-submit depth feeds the ``jepsen_engine_inflight_depth``
+    high-water gauge.  The dispatch call is an ``engine/issue`` span
+    and ``on_retire`` an ``engine/settle`` span.
     """
 
     def __init__(
@@ -168,7 +170,6 @@ class DispatchWindow:
         #: (key, lazy-out, t_dispatch, attrs)
         self._inflight: deque = deque()  # jt: guarded-by(owner-thread)
         self.peak_depth = 0  # jt: guarded-by(owner-thread)
-        self.bubble_s = 0.0  # jt: guarded-by(owner-thread)
         self.submitted = 0  # jt: guarded-by(owner-thread)
         self._owner = threading.get_ident()
 
@@ -195,7 +196,11 @@ class DispatchWindow:
         # synchronously inside the first dispatch call, and the
         # compile-vs-execute histograms must keep containing them
         t_dispatch = time.perf_counter()
-        out = thunk()
+        # enqueue + host-to-device copy, and the compile on a miss
+        with obs.span("engine/issue", cat="engine") as sp:
+            out = thunk()
+            if sp and attrs:
+                sp.set("rows", attrs.get("rows"))
         self._inflight.append((key, out, t_dispatch, attrs))
         self.submitted += 1
         depth = len(self._inflight)
@@ -215,10 +220,12 @@ class DispatchWindow:
         else:
             mat = _materialize(out)
         wait = time.perf_counter() - t0
-        self.bubble_s += wait
         obs.observe("jepsen_engine_bubble_seconds", wait)
         if self.on_retire is not None:
-            self.on_retire(key, mat, t_dispatch)
+            with obs.span("engine/settle", cat="engine") as sp:
+                self.on_retire(key, mat, t_dispatch)
+                if sp and attrs:
+                    sp.set("rows", attrs.get("rows"))
         return key, mat, t_dispatch
 
     def drain(self) -> list:
@@ -366,10 +373,6 @@ class Executor:
     def peak_depth(self) -> int:
         return self._win.peak_depth
 
-    @property
-    def bubble_s(self) -> float:
-        return self._win.bubble_s
-
     # -- settle path (runs inside window retirement, owner thread) -------
 
     def _settle_chunk(self, chunk_id, mat, t_dispatch):
@@ -453,16 +456,21 @@ class Executor:
 
     def _settle_rows(self, plan, arrays, rows, ok, failed_at, overflow):
         """Escalate a chunk's overflows on-device, then assign verdicts
-        (still-overflowed rows join each row's oracle pool)."""
+        (still-overflowed rows join each row's oracle pool): the
+        deferred escalation pass of :meth:`drain`, and the inline
+        settle of a bucket no kernel can run."""
         from ..ops import wgl
 
-        wgl.escalate_overflows(
-            plan, arrays, ok, failed_at, overflow,
-            mesh=self.mesh, escalation=self.escalation,
-            sufficient_rung=self.sufficient_rung,
-            max_dispatch=self.max_dispatch,
-        )
-        self._assign_rows(plan, rows, ok, failed_at, overflow)
+        with obs.span("engine/settle", cat="engine") as sp:
+            wgl.escalate_overflows(
+                plan, arrays, ok, failed_at, overflow,
+                mesh=self.mesh, escalation=self.escalation,
+                sufficient_rung=self.sufficient_rung,
+                max_dispatch=self.max_dispatch,
+            )
+            self._assign_rows(plan, rows, ok, failed_at, overflow)
+            if sp:
+                sp.set("rows", len(rows))
 
     def _assign_rows(self, plan, rows, ok, failed_at, overflow):
         unresolved = "routed" if plan.kernel == "oracle" else "overflow"

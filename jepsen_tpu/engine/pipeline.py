@@ -73,13 +73,15 @@ Pipeline telemetry (obs registry; doc/observability.md):
   waiting on an in-flight dispatch — the stall the window hides.
 - ``jepsen_engine_bucket_count`` (gauge, high-water): peak shape
   buckets one batch split into.
-- ``jepsen_engine_occupancy_ratio`` (gauge): 1 − bubble/wall for the
-  last engine run.
+
+Spans (category ``engine``) nest under ``engine/pipeline`` on its
+thread: ``engine/encode`` per history, ``engine/plan`` per flush,
+``engine/issue`` per dispatch, ``engine/dispatch`` per wait on one,
+``engine/settle`` per retired dispatch or escalation pass.
 """
 
 from __future__ import annotations
 
-import time
 from typing import List, Optional, Sequence
 
 from .. import obs
@@ -158,7 +160,6 @@ def run(
         sufficient_rung=sufficient_rung, max_dispatch=max_dispatch,
     )
 
-    t0 = time.perf_counter()
     n_buckets = n_flushes = 0
     with obs.span("engine/pipeline", cat="engine") as sp:
         # -- stage 0+1+2 interleaved: the decomposition front-end's
@@ -202,7 +203,6 @@ def run(
         for pb in finished:
             ex.submit(pb)
         ex.drain()
-        t_device_end = time.perf_counter()
 
         # -- stage 3 drain: collect concurrent oracle verdicts
         dec.drain_oracles()
@@ -224,16 +224,6 @@ def run(
     if obs.enabled():
         if n_buckets:
             obs.gauge_max("jepsen_engine_bucket_count", n_buckets)
-        # occupancy over the DEVICE phase only (encode→dispatch→drain→
-        # escalate): including the stage-3 oracle drain would let an
-        # oracle-dominated run report near-100% occupancy while the
-        # device sat idle — the opposite of what the metric diagnoses
-        elapsed = t_device_end - t0
-        if ex.submitted and elapsed > 0:
-            obs.gauge_set(
-                "jepsen_engine_occupancy_ratio",
-                max(0.0, 1.0 - ex.bubble_s / elapsed),
-            )
         finish_run_telemetry(results)
 
     return results

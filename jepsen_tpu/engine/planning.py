@@ -35,6 +35,8 @@ from __future__ import annotations
 import os
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from .. import obs
+
 #: rows a shape bucket accumulates before flushing mid-stream.  Kept at
 #: the default dispatch cap so ordinary batches flush exactly once per
 #: bucket (identical routing/compile behavior to the one-shot encode),
@@ -56,8 +58,6 @@ def _note_settle_times(result: dict) -> None:
     FIRST settle and the FIRST ``valid? = false`` verdict win.  This is
     the summary seam the online monitor keys on (a violation at op 40k
     should show a detect time near op 40k, not at run end)."""
-    from .. import obs
-
     if not obs.enabled():
         return
     import time as _time
@@ -352,18 +352,23 @@ class Planner:
         only its unsettled partitions."""
         if ctx.settled(idx):
             return _ROUTED_ORACLE
-        e = self.encode_one(ctx, idx)
-        if e is None:
-            ctx.route_oracle(idx, "oracle-fallback", "unencodable")
-            return _ROUTED_ORACLE
-        key = self.bucket_key(e)
-        acc = buckets.get(key)
-        if acc is None:
-            acc = buckets[key] = ([], [])
-            order.append(key)
-        acc[0].append(e)
-        acc[1].append((ctx, idx))
-        return key
+        # the span covers the history's way into its bucket too
+        with obs.span("engine/encode", cat="engine") as sp:
+            e = self.encode_one(ctx, idx)
+            if sp:
+                sp.set("events",
+                       0 if e is None else len(ctx.histories[idx]))
+            if e is not None:
+                key = self.bucket_key(e)
+                acc = buckets.get(key)
+                if acc is None:
+                    acc = buckets[key] = ([], [])
+                    order.append(key)
+                acc[0].append(e)
+                acc[1].append((ctx, idx))
+                return key
+        ctx.route_oracle(idx, "oracle-fallback", "unencodable")
+        return _ROUTED_ORACLE
 
     def encode_buckets(self, ctx: RunContext):
         """Encode every history of ``ctx`` into raw (unstacked) shape
@@ -400,21 +405,29 @@ class Planner:
 
         if not encs:
             return None
-        if key is not None:
-            E, C = key
-        else:
-            # unbucketed (historical) stacking: one global padded shape
-            E, C = encode_mod.global_shape(encs, self.slot_cap)
-        batch = encode_mod.stack_encoded(encs, rows, E, C)
-        arrays = (
-            batch.init_state, batch.ev_slot, batch.cand_slot,
-            batch.cand_f, batch.cand_a, batch.cand_b,
-        )
-        self.n_flushes += 1
-        plan = wgl.plan_bucket(
-            self.model, self.spec, arrays, frontier=self.frontier,
-            max_closure=self.max_closure, max_dispatch=self.max_dispatch,
-        )
+        with obs.span("engine/plan", cat="engine") as sp:
+            if key is not None:
+                E, C = key
+            else:
+                # unbucketed (historical) stacking: one global padded
+                # shape
+                E, C = encode_mod.global_shape(encs, self.slot_cap)
+            batch = encode_mod.stack_encoded(encs, rows, E, C)
+            arrays = (
+                batch.init_state, batch.ev_slot, batch.cand_slot,
+                batch.cand_f, batch.cand_a, batch.cand_b,
+            )
+            self.n_flushes += 1
+            plan = wgl.plan_bucket(
+                self.model, self.spec, arrays, frontier=self.frontier,
+                max_closure=self.max_closure,
+                max_dispatch=self.max_dispatch,
+            )
+            if sp:
+                sp.set("rows", len(encs))
+                sp.set("E", E)
+                sp.set("C", C)
+                sp.set("kernel", plan.kernel)
         return PlannedBucket(key, plan, arrays, batch.row_history)
 
     # -- the streaming composition (in-process pipeline) ------------------
@@ -564,7 +577,6 @@ def finish_run_telemetry(results: Sequence[Optional[dict]]) -> None:
     """Per-subhistory engine-outcome counters (the observable half of
     P-compositional tuning): tpu rows count under their kernel name,
     everything else under its engine tag."""
-    from .. import obs
     from ..ops import wgl
 
     if not (obs.enabled() and results):

@@ -22,6 +22,7 @@ import os
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
 from . import generator as gen
+from . import obs
 from . import store as store_mod
 from .checker import Checker, check_safe, merge_valid
 from .history import History, Op
@@ -339,10 +340,15 @@ class _BatchedLinearizable(Checker):
         # identical in-process wgl.check_batch
         from .serve import client as serve_client
 
-        ks = sorted(history_keys(history), key=str)
-        subs = [
-            subhistory(k, history).client_ops().index_ops() for k in ks
-        ]
+        # the per-key split walks the whole history once per key
+        with obs.span("checker/split", cat="checker") as sp:
+            ks = sorted(history_keys(history), key=str)
+            subs = [
+                subhistory(k, history).client_ops().index_ops() for k in ks
+            ]
+            if sp:
+                sp.set("keys", len(ks))
+                sp.set("ops", sum(len(h) for h in subs))
         per_key = serve_client.check_batch(
             self.model,
             subs,

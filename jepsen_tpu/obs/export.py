@@ -279,18 +279,14 @@ def summary(tracer: Tracer, registry: MetricsRegistry) -> dict:
     budget = registry.value("jepsen_frontier_dispatch_budget_used_ratio")
     if budget is not None:
         out["frontier-dispatch-budget-used"] = round(budget, 4)
-    # pipelined-engine occupancy (jepsen_tpu.engine): peak in-flight
-    # dispatch depth (>1 proves overlap happened), peak shape-bucket
-    # count, and the last run's 1 − bubble/wall occupancy ratio
+    # pipelined engine (jepsen_tpu.engine): peak in-flight dispatch
+    # depth (>1 proves overlap happened) and peak shape-bucket count
     depth = registry.value("jepsen_engine_inflight_depth")
     if depth is not None:
         out["engine-inflight-depth"] = int(depth)
     nb = registry.value("jepsen_engine_bucket_count")
     if nb is not None:
         out["engine-buckets"] = int(nb)
-    occ = registry.value("jepsen_engine_occupancy_ratio")
-    if occ is not None:
-        out["engine-occupancy"] = round(occ, 4)
     # online-checking latency: seconds from the run's wall origin to
     # the first settled verdict / first violation verdict (the gauges
     # set once by engine.planning as partitions settle)
@@ -351,10 +347,7 @@ def format_summary(s: dict) -> str:
     if s.get("frontier-high-water") is not None:
         extras.append(f"frontier high-water: {int(s['frontier-high-water'])}")
     if s.get("engine-inflight-depth") is not None:
-        pipe = f"pipeline depth: {s['engine-inflight-depth']}"
-        if s.get("engine-occupancy") is not None:
-            pipe += f", occupancy: {s['engine-occupancy']:.0%}"
-        extras.append(pipe)
+        extras.append(f"pipeline depth: {s['engine-inflight-depth']}")
     if s.get("time-to-first-verdict") is not None:
         online = f"first verdict: {s['time-to-first-verdict']:.3f}s"
         if s.get("time-to-violation") is not None:

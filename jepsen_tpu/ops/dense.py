@@ -436,7 +436,8 @@ def build_dense(
     if union_matmul:
         Pu, Pd = _subset_perms(C)
 
-    def check_one(init_state, ev_slot, cand_slot, cand_f, cand_a, cand_b):
+    def dense_check_one(init_state, ev_slot, cand_slot, cand_f, cand_a,
+                        cand_b):
         if multi:
             # packed byte-per-register int32 → composite state id
             from .step_kernels import MR_VALUE_BITS
@@ -626,7 +627,7 @@ def build_dense(
         )
         return ~done, failed_at, jnp.bool_(False)
 
-    return jax.vmap(check_one)
+    return jax.vmap(dense_check_one)
 
 
 def build_dense_queue(E: int, C: int, union: str = "gather"):
@@ -658,7 +659,8 @@ def build_dense_queue(E: int, C: int, union: str = "gather"):
     ones = jnp.full((W,), 0xFFFFFFFF, jnp.uint32)
     zeros = jnp.zeros((W,), jnp.uint32)
 
-    def check_one(init_state, ev_slot, cand_slot, cand_f, cand_a, cand_b):
+    def dense_queue_check_one(init_state, ev_slot, cand_slot, cand_f, cand_a,
+                              cand_b):
         D0 = jnp.zeros((W,), jnp.uint32).at[0].set(1)  # empty linset
         enqC0 = init_state.astype(jnp.uint32)  # initial contents bitset
         deqC0 = jnp.uint32(0)
@@ -789,7 +791,7 @@ def build_dense_queue(E: int, C: int, union: str = "gather"):
         )
         return ~done, failed_at, jnp.bool_(False)
 
-    return jax.vmap(check_one)
+    return jax.vmap(dense_queue_check_one)
 
 
 def make_dense_fn(spec_name: str, E: int, C: int, V):

@@ -316,7 +316,8 @@ def build_batched(
     compact = _COMPACTIONS[compaction]
     W = (C + 31) // 32  # linset words: one uint32 per 32 open-op slots
 
-    def check_one(init_state, ev_slot, cand_slot, cand_f, cand_a, cand_b):
+    def frontier_check_one(init_state, ev_slot, cand_slot, cand_f, cand_a,
+                           cand_b):
         states0 = jnp.zeros((F,), jnp.int32).at[0].set(init_state)
         words0 = tuple(jnp.zeros((F,), jnp.uint32) for _ in range(W))
         valid0 = jnp.zeros((F,), bool).at[0].set(True)
@@ -415,7 +416,7 @@ def build_batched(
         )
         return ~done, failed_at, overflow
 
-    return jax.vmap(check_one)
+    return jax.vmap(frontier_check_one)
 
 
 def default_compaction() -> str:
@@ -824,8 +825,9 @@ def _run_chunked(fn, mesh, arrays, max_batch=DEFAULT_MAX_DISPATCH):
 
 
 def _timed_run_chunked(fn, mesh, arrays, disp, engine):
-    """:func:`_run_chunked` with engine telemetry: one ``engine`` span
-    per dispatch call, wall time split into *compile* (the first
+    """:func:`_run_chunked` with engine telemetry: one
+    ``engine/escalate`` span per dispatch call (only escalation reruns
+    dispatch synchronously), wall time split into *compile* (the first
     dispatch of this compiled fn — trace + XLA compile + execute) vs
     *execute* (every later dispatch, cache-hit).  The timed region
     forces host materialization so async dispatch can't under-report;
@@ -881,7 +883,7 @@ def _timed_run_chunked(fn, mesh, arrays, disp, engine):
     first = _claim_shape(fn, disp_shape)
     phase = "compile" if first else "execute"
     with obs.span(
-        "engine/dispatch", cat="engine",
+        "engine/escalate", cat="engine",
         engine=engine, rows=B, phase=phase,
     ) as sp:
         out = tuple(

@@ -1,0 +1,13 @@
+"""Engine host: the share of the engine's wall time spent stacking and
+routing buckets, 100 x engine/plan / engine/pipeline, over the
+window."""
+
+from _self_time import any_span
+from _spans import seconds
+
+
+def read(run):
+    engine = seconds(run, "engine/pipeline")
+    if not engine or not any_span(run, ("engine/plan",)):
+        return None
+    return 100.0 * seconds(run, "engine/plan") / engine

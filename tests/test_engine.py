@@ -349,21 +349,114 @@ def test_pipeline_metrics_and_span():
     assert reg.value("jepsen_engine_bucket_count") == len(
         encode.batch_encode(hists, model, bucketed=True)
     )
-    occ = reg.value("jepsen_engine_occupancy_ratio")
-    assert occ is not None and 0.0 <= occ <= 1.0
     bubble = [
         d for d in reg.snapshot()
         if d["name"] == "jepsen_engine_bubble_seconds"
     ]
     assert bubble and bubble[0]["count"] > 0
-    names = {s.name for s in obs.tracer().finished(cat="engine")}
-    assert "engine/pipeline" in names
-    assert "engine/dispatch" in names
+    spans = obs.tracer().finished(cat="engine")
+    (pipe,) = [s for s in spans if s.name == "engine/pipeline"]
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s.name, []).append(s)
+    # one encode per history, carrying the history's event count
+    encs = by_name["engine/encode"]
+    assert len(encs) == len(hists)
+    assert sum(int(s.attrs["events"]) for s in encs) == sum(
+        len(x) for x in hists)
+    # one plan per flush, one issue per dispatch, a settle per retire
+    assert len(by_name["engine/plan"]) == int(pipe.attrs["flushes"])
+    for s in by_name["engine/plan"]:
+        assert {"rows", "E", "C", "kernel"} <= set(s.attrs)
+    assert len(by_name["engine/issue"]) == int(pipe.attrs["chunks"]) > 1
+    assert len(by_name["engine/settle"]) >= len(by_name["engine/dispatch"])
+    assert by_name["engine/dispatch"]
+    for name in ("engine/encode", "engine/plan", "engine/issue",
+                 "engine/dispatch", "engine/settle"):
+        for s in by_name[name]:
+            assert s.parent == pipe.sid, name
+            assert pipe.t0 <= s.t0 <= s.t1 <= pipe.t1
     # the summary surfaces the pipeline facts
     s = obs.summary()
     assert s.get("engine-inflight-depth", 0) > 1
-    assert "engine-occupancy" in s
+    assert "engine-occupancy" not in s
     obs.enable(reset=True)
+
+
+def test_keyed_check_records_one_split_span():
+    from jepsen_tpu import independent
+    from jepsen_tpu.history import Op
+
+    rng = random.Random(7)
+    history = History([
+        Op(op.type, op.process, op.f, independent.kv(k, op.value))
+        for k in range(5)
+        for op in _gen(rng, n_procs=3, n_ops=8, crash_p=0.0)
+    ]).index_ops()
+    obs.enable(reset=True)
+    res = independent.batched_linearizable(m.cas_register(0)).check(
+        {"store?": False}, history)
+    assert len(res["results"]) == 5
+    (split,) = [s for s in obs.tracer().finished()
+                if s.name == "checker/split"]
+    assert split.cat == "checker"
+    assert split.attrs["keys"] == "5"
+    assert int(split.attrs["ops"]) == len(history.client_ops())
+    # the split runs before the engine, outside its span
+    (pipe,) = [s for s in obs.tracer().finished()
+               if s.name == "engine/pipeline"]
+    assert split.t1 <= pipe.t0
+    obs.enable(reset=True)
+
+
+def test_engine_spans_mirror_onto_the_profiler_trace(tmp_path):
+    """With obs on, every engine span is also a profiler annotation of
+    the same name on the host plane, nested as the spans nest."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    hists = mixed_corpus(wide=False)
+    obs.enable(reset=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        wgl.check_batch(m.cas_register(0), hists, window=4, bucketed=True,
+                        max_dispatch=3)
+    finally:
+        jax.profiler.stop_trace()
+    spans = [s for s in obs.tracer().finished()
+             if s.name.startswith("engine/")]
+    obs.enable(reset=True)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("engine/"):
+                        host.setdefault(ev.name, []).append(
+                            (ev.start_ns, ev.start_ns + ev.duration_ns))
+    by_name = {}
+    for s in sorted(spans, key=lambda s: s.t0):
+        by_name.setdefault(s.name, []).append(s)
+    assert {"engine/pipeline", "engine/encode", "engine/plan",
+            "engine/issue", "engine/dispatch",
+            "engine/settle"} <= set(by_name)
+    event_of = {}
+    for name, ss in by_name.items():
+        evs = sorted(host.get(name, []))
+        assert len(evs) == len(ss), name
+        for s, e in zip(ss, evs):
+            event_of[s.sid] = e
+    for s in spans:
+        if s.parent in event_of:
+            (ps, pe), (cs, ce) = event_of[s.parent], event_of[s.sid]
+            assert ps <= cs <= ce <= pe, s.name
 
 
 def test_window_one_records_serial_depth():

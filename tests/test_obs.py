@@ -6,6 +6,7 @@ the ADVICE r5 bench fixes that live at the obs/bench reporting seam."""
 
 import json
 import os
+import sys
 import threading
 
 import pytest
@@ -14,6 +15,10 @@ from jepsen_tpu import obs
 from jepsen_tpu.obs import export as export_mod
 from jepsen_tpu.obs.metrics import MetricsRegistry
 from jepsen_tpu.obs.tracer import NULL_SPAN, Tracer
+
+#: the tracer module itself: the package attribute ``obs.tracer`` is
+#: the accessor function, which shadows it
+tracer_mod = sys.modules["jepsen_tpu.obs.tracer"]
 
 
 @pytest.fixture(autouse=True)
@@ -107,6 +112,97 @@ def test_disabled_mode_allocates_nothing():
     assert len(obs.tracer()) == 0
     assert obs.registry().snapshot() == []
     assert obs.registry().prometheus_text() == ""
+
+
+def test_disabled_engine_and_split_spans_record_nothing(monkeypatch):
+    """The engine's encode/plan/issue/dispatch/settle call sites and the
+    keyed checker's split, with obs off: no record, no metric and no
+    profiler annotation."""
+    import random
+
+    from jepsen_tpu import independent
+    from jepsen_tpu import models as m
+    from jepsen_tpu.history import History, Op
+    from jepsen_tpu.ops import wgl
+    from jepsen_tpu.synth import generate_history as gen
+
+    def no_annotation(name):
+        raise AssertionError(f"annotation {name} opened with obs off")
+
+    monkeypatch.setattr(tracer_mod, "_profiler_annotation", no_annotation)
+    rng = random.Random(3)
+    hists = [gen(rng, n_procs=3, n_ops=n, crash_p=0.0) for n in (6, 30)]
+    keyed = History([
+        Op(op.type, op.process, op.f, independent.kv(k, op.value))
+        for k in range(3) for op in gen(rng, n_procs=3, n_ops=6)
+    ]).index_ops()
+    obs.disable()
+    wgl.check_batch(m.cas_register(0), hists, window=2, max_dispatch=1)
+    independent.batched_linearizable(m.cas_register(0)).check(
+        {"store?": False}, keyed)
+    assert len(obs.tracer()) == 0
+    assert obs.registry().snapshot() == []
+
+
+def test_spans_mirror_as_profiler_annotations(monkeypatch):
+    """An enabled span opens and closes one profiler annotation of its
+    own name around its interval, on its own thread."""
+    log = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name, threading.get_ident()))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name, threading.get_ident()))
+
+    monkeypatch.setattr(tracer_mod, "_profiler_annotation", Ann)
+    with obs.span("outer", cat="engine"):
+        with obs.span("inner", cat="engine"):
+            pass
+    me = threading.get_ident()
+    assert log == [("enter", "outer", me), ("enter", "inner", me),
+                   ("exit", "inner", me), ("exit", "outer", me)]
+
+
+def test_spans_record_the_pid_kept_current_across_fork(monkeypatch):
+    """Spans carry the process id without a system call each; the fork
+    hook refreshes it in a child."""
+    with obs.span("a"):
+        pass
+    assert obs.tracer().finished()[-1].pid == os.getpid()
+    monkeypatch.setattr(os, "getpid", lambda: 4242)
+    tracer_mod._refresh_pid()  # what a forked child runs
+    with obs.span("b"):
+        pass
+    assert obs.tracer().finished()[-1].pid == 4242
+    monkeypatch.undo()
+    tracer_mod._refresh_pid()
+    assert tracer_mod._pid == os.getpid()
+
+
+def test_obs_mirror_needs_no_jax():
+    """obs imports and records spans without importing JAX; the mirror
+    only starts once JAX is in the process."""
+    import subprocess
+
+    code = ("import sys\n"
+            "from jepsen_tpu import obs\n"
+            "tracer = sys.modules['jepsen_tpu.obs.tracer']\n"
+            "obs.enable()\n"
+            "with obs.span('a'): pass\n"
+            "assert 'jax' not in sys.modules\n"
+            "assert tracer._profiler_annotation('a') is None\n"
+            "import jax\n"
+            "assert tracer._profiler_annotation('a') is not None\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
 
 
 # ---------------------------------------------------------------------------
