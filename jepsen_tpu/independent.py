@@ -12,7 +12,10 @@ checker per key with bounded host parallelism (reference
 independent.clj:266-317); :func:`batched_linearizable` instead gathers
 *all* per-key subhistories into one encoded batch and submits a single
 vmapped/sharded WGL search (jepsen_tpu.ops.wgl.check_batch) — one device
-dispatch for the whole keyspace instead of one search per key.
+dispatch for the whole keyspace instead of one search per key.  Both split
+the history with :func:`subhistories`: one pass over the history for all
+keys, not one per key, so the split grows with the history's length and
+not with its square.
 """
 
 from __future__ import annotations
@@ -272,6 +275,32 @@ def subhistory(k, history: History) -> History:
     return out
 
 
+def subhistories(history: History) -> Dict[Any, History]:
+    """Every key's :func:`subhistory`, from one walk of the history:
+    equal, op for op, to ``{k: subhistory(k, history) for k in
+    history_keys(history)}``.  Each sub-history holds its own copy of
+    every op, un-keyed ones included, so re-indexing one sub-history
+    never shows through in another or in ``history``."""
+    subs = {k: History() for k in history_keys(history)}
+    for op in history:
+        # Op(...) rather than op.copy(...), whose keyword-update path
+        # nearly doubles the cost of this loop; and the keyed op, nearly
+        # every op, takes no inner loop
+        v = op.value
+        if is_tuple(v):
+            k, x = v
+            subs[k].append(
+                Op(op.type, op.process, op.f, x, op.time, op.index, **op.extra)
+            )
+        else:
+            for h in subs.values():
+                h.append(
+                    Op(op.type, op.process, op.f, v, op.time, op.index,
+                       **op.extra)
+                )
+    return subs
+
+
 def _write_key_artifacts(test: dict, subdir: list, result: dict, h: History) -> None:
     """Persist per-key results + history under the test's store dir.
     (reference: independent.clj:295-303)"""
@@ -292,10 +321,11 @@ class _IndependentChecker(Checker):
 
     def check(self, test, history, opts=None):
         opts = opts or {}
-        ks = sorted(history_keys(history), key=str)
+        subs = subhistories(history)
+        ks = sorted(subs, key=str)
 
         def check_key(k):
-            h = subhistory(k, history).index_ops()
+            h = subs[k].index_ops()
             subdir = list(opts.get("subdirectory", [])) + [DIR, str(k)]
             result = check_safe(
                 self.chk, test, h,
@@ -340,12 +370,10 @@ class _BatchedLinearizable(Checker):
         # identical in-process wgl.check_batch
         from .serve import client as serve_client
 
-        # the per-key split walks the whole history once per key
         with obs.span("checker/split", cat="checker") as sp:
-            ks = sorted(history_keys(history), key=str)
-            subs = [
-                subhistory(k, history).client_ops().index_ops() for k in ks
-            ]
+            by_key = subhistories(history)
+            ks = sorted(by_key, key=str)
+            subs = [by_key[k].client_ops().index_ops() for k in ks]
             if sp:
                 sp.set("keys", len(ks))
                 sp.set("ops", sum(len(h) for h in subs))
