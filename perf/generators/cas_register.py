@@ -1,18 +1,21 @@
-"""The one traffic generator: CAS-register histories from a traffic
-file's parameters and a seed, as plain tuples.
+"""The CAS-register generator: histories from a configuration, a
+traffic file's parameters and a seed, as plain tuples.
 
 A history is a list of events ``(type, process, f, value)`` with
 ``type`` in invoke/ok/fail/info, ``f`` in read/write/cas and a cas
 value the pair ``(old, new)``.  Nothing here imports the program; the
 cell's entry (``perf/entries/``) turns events into the program's ops.
+A corruption writes one of ``CORRUPT_VALUES``, above every value an
+honest op writes: ``perf/reference/cas_register.py`` knows the verdict
+of a history from that.
 
 ``single_history`` is a copy of ``jepsen_tpu.synth.generate_history``
-(same RNG stream), ``known_verdict`` of ``synth.known_verdict``.
-``keyed_test`` simulates ``jepsen.tests.linearizable-register``: keys
-run one after another on one group of threads, half of them reading
-and half mixing ``[w cas cas]``, each key's ops limited to the jittered
-per-key limit and its processes to the process limit, a crashed
-process retired under a fresh id (``process + threads``).
+(same RNG stream).  ``keyed_test`` simulates
+``jepsen.tests.linearizable-register``: keys run one after another on
+one group of threads, half of them reading and half mixing
+``[w cas cas]``, each key's ops limited to the jittered per-key limit
+and its processes to the process limit, a crashed process retired
+under a fresh id (``process + threads``).
 """
 
 from __future__ import annotations
@@ -214,17 +217,16 @@ def make_pool(cfg, traffic, seed):
     raise ValueError(f"unknown traffic shape {kind!r}")
 
 
-def known_verdict(hist, n_values):
-    """The verdict the generator knows: True for an honest history (it
-    linearizes at each completion), False where an ok read returned a
-    value nobody wrote, None where a corrupted write leaves it open."""
-    for t, _p, f, v in hist:
-        if t == OK and f in ("read", "write") and v is not None \
-                and v > n_values:
-            return False if f == "read" else None
-    return True
-
-
 def client_ops(hist):
     """Invocations in a history: what ``ops_per_s`` counts."""
     return sum(1 for e in hist if e[0] == INVOKE)
+
+
+def request_size(request, traffic):
+    """(client ops, events) of one request: the invocations
+    ``ops_per_s`` counts, and the history events the kernels' bytes
+    are counted from."""
+    hists = request if traffic["shape"] == "single_key" else [
+        h for _k, h in request[0]]
+    return (sum(client_ops(h) for h in hists),
+            sum(len(h) for h in hists))

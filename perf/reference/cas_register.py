@@ -16,6 +16,11 @@ linearized.  A configuration in which fewer crashed ops have fired
 than another with the same value and the same open ops linearized can
 do all that the other can, so the larger one is dropped.  Nothing here
 imports the program.
+
+``known`` and ``search`` give the harness (``perf/verify.py``) an
+item's labels: ``"valid?"`` for a ``single_key`` history, and for a
+``keyed`` test one ``("key", k)`` per key beside the merged
+``"valid?"``.
 """
 
 from __future__ import annotations
@@ -136,3 +141,47 @@ def linearizable(history, init=0, crashed_take_effect=True):
         free.append(done)
     return True
 
+
+def known_verdict(hist, n_values):
+    """The verdict the generator knows (a copy of
+    ``jepsen_tpu.synth.known_verdict``): True for an honest history (it
+    linearizes at each completion), False where an ok read returned a
+    value nobody wrote (the generator's corruptions write values above
+    ``n_values``), None where a corrupted write leaves it open."""
+    for t, _p, f, v in hist:
+        if t == "ok" and f in ("read", "write") and v is not None \
+                and v > n_values:
+            return False if f == "read" else None
+    return True
+
+
+def known(cfg, traffic, item):
+    """Every label of one pool item with the verdict the generator
+    knows for it, or None: ``"valid?"`` for a ``single_key`` history;
+    for a ``keyed`` test one ``("key", k)`` per key and the merged
+    ``"valid?"``, False once a key is known invalid, True once every
+    key is known valid."""
+    if traffic["shape"] == "single_key":
+        return {"valid?": known_verdict(item, cfg["values"])}
+    out = {("key", k): known_verdict(h, cfg["values"]) for k, h in item}
+    verdicts = list(out.values())
+    out["valid?"] = (False if False in verdicts
+                     else True if None not in verdicts else None)
+    return out
+
+
+def search(cfg, traffic, item, labels, crashed_take_effect=True):
+    """The labels asked for, each decided by ``linearizable`` alone; a
+    keyed test's merged ``"valid?"`` by searching every key.  With
+    ``crashed_take_effect`` false, the control's search."""
+    def lin(hist):
+        return linearizable(hist, cfg["init"], crashed_take_effect)
+
+    if traffic["shape"] == "single_key":
+        return {"valid?": lin(item)}
+    keys = {("key", k): h for k, h in item}
+    todo = keys if "valid?" in labels else {lb: keys[lb] for lb in labels}
+    out = {lb: lin(h) for lb, h in todo.items()}
+    if "valid?" in labels:
+        out["valid?"] = all(out.values())
+    return out

@@ -5,11 +5,28 @@
 The cell, its configuration and its traffic come from ``BENCHMARK.json``
 at the root of the checkout; each is found by name: the configuration
 in ``perf/configs/<config>.json``, the traffic in
-``perf/traffic/<traffic>.json``, the program's entry in
-``perf/entries/<entry>.py``, the reference in
-``perf/reference/<reference>.py``, each end-to-end metric in
-``perf/end_to_end/<metric>.py`` and each per-layer metric in
-``perf/metrics/<metric>.py``.
+``perf/traffic/<traffic>.json``, and the configuration's ``generator``,
+``entry`` and ``reference`` in ``perf/generators/<generator>.py``,
+``perf/entries/<entry>.py`` and ``perf/reference/<reference>.py``; each
+end-to-end metric in ``perf/end_to_end/<metric>.py`` and each per-layer
+metric in ``perf/metrics/<metric>.py``.  Nothing here knows a data
+model: a configuration of another one comes as new files of those
+kinds.
+
+The generator makes the cell's traffic pool, a list of requests each a
+list of items, with ``make_pool(cfg, traffic, seed)``, and gives a
+request's size with ``request_size(request, traffic) -> (client ops,
+events)``.  The entry turns a request into the program's input with
+``prepare(cfg, items)``, runs it with ``check(prepared)``, and reads
+the result with ``rows(result, n_items) -> ([{label: verdict}] per
+item, [(engine, kernel)] per device row)``.  The reference gives an
+item's labels with the verdicts the generator knows, ``known(cfg,
+traffic, item) -> {label: verdict or None}``, and decides labels by its
+own search, ``search(cfg, traffic, item, labels) -> {label: verdict}``
+(``perf/verify.py``).  A metric named ``<base>.<variant>`` with no
+reader file of its own is read by ``<base>.py``: a new cell takes an
+existing per-layer quantity as a new ``BENCHMARK.json`` entry that
+lists it.
 
 A run makes the cell's traffic pool from ``--seed``, warms up by
 checking every request of the pool once and then more until one
@@ -73,10 +90,27 @@ def load_json(*parts):
         return json.load(f)
 
 
-class Cell:
-    """One ``workloads`` entry and everything it names."""
+#: where the modules a configuration names live, by its key
+MODULE_DIRS = {"generator": "generators", "entry": "entries",
+               "reference": "reference"}
 
-    def __init__(self, bench, name):
+
+def reader(kind_dir, name):
+    """The reader module of metric ``name``: ``<name>.py``, or for a
+    ``<base>.<variant>`` with no file of its own, ``<base>.py``."""
+    own = name + ".py"
+    if "." in name and not os.path.isfile(os.path.join(PERF, kind_dir,
+                                                        own)):
+        own = name.split(".", 1)[0] + ".py"
+    return load_module(kind_dir, own)
+
+
+class Cell:
+    """One ``workloads`` entry and everything it names; ``shrink``
+    ({"config": {...}, "traffic": {...}}) is merged over its files, as
+    the tests cut a cell to a test run's size."""
+
+    def __init__(self, bench, name, shrink=None):
         cells = {w["name"]: w for w in bench["workloads"]}
         if name not in cells:
             raise RunError(f"no workload {name!r} in BENCHMARK.json")
@@ -84,7 +118,14 @@ class Cell:
         self.name = name
         self.chips = w["chips"]
         self.config = load_json("configs", w["config"] + ".json")
+        for key in MODULE_DIRS:
+            if key not in self.config:
+                raise RunError(f"configuration {w['config']!r} names no "
+                               f"{key}")
         self.traffic = load_json("traffic", w["traffic"] + ".json")
+        if shrink:
+            self.config.update(shrink.get("config", {}))
+            self.traffic.update(shrink.get("traffic", {}))
 
         def mine(m):
             return name in m.get("workloads", [name])
@@ -93,6 +134,11 @@ class Cell:
         reported = {m["name"] for m in self.end_to_end}
         self.per_layer = [m for m in bench["per_layer"]
                           if m["moves"] in reported and mine(m)]
+
+    def module(self, key):
+        """The configuration's ``generator``, ``entry`` or
+        ``reference``."""
+        return load_module(MODULE_DIRS[key], self.config[key] + ".py")
 
 
 def hygiene():
@@ -244,6 +290,7 @@ class Run:
         self.requests = []
         self.window_compiles = None
         self.spans = []
+        self.spans_dropped = None
         self.obs_metrics = []
         self.profile = None
         self.peaks = None
@@ -257,13 +304,6 @@ def window_order(n):
     bits = max(1, (n - 1).bit_length())
     return [i for i in (int(format(j, f"0{bits}b")[::-1], 2)
                         for j in range(1 << bits)) if i < n]
-
-
-def make_pool(cell, seed):
-    gen = load_module("traffic", "gen.py")
-    t0 = time.perf_counter()
-    pool = gen.make_pool(cell.config, cell.traffic, seed)
-    return gen, pool, time.perf_counter() - t0
 
 
 def run_cell(argv=None, allow_cpu=False, wrap_check=None, shrink=None):
@@ -286,13 +326,11 @@ def run_cell(argv=None, allow_cpu=False, wrap_check=None, shrink=None):
         raise RunError("no BENCHMARK.json at the checkout's root")
     with open(bench_path) as f:
         bench = json.load(f)
-    cell = Cell(bench, args.workload)
-    if shrink:
-        cell.config.update(shrink.get("config", {}))
-        cell.traffic.update(shrink.get("traffic", {}))
+    cell = Cell(bench, args.workload, shrink)
     run = Run()
-    entry = load_module("entries", cell.config["entry"] + ".py")
-    ref = load_module("reference", cell.config["reference"] + ".py")
+    gen = cell.module("generator")
+    entry = cell.module("entry")
+    ref = cell.module("reference")
     verify = load_module("verify.py")
     peaks_table = load_json("peaks.json")
 
@@ -310,7 +348,9 @@ def run_cell(argv=None, allow_cpu=False, wrap_check=None, shrink=None):
     print(json.dumps({"engine": engine_knobs(), "cache_dir": CACHE_DIR}),
           flush=True)
 
-    gen, pool, gen_s = make_pool(cell, args.seed)
+    t0 = time.perf_counter()
+    pool = gen.make_pool(cell.config, cell.traffic, args.seed)
+    gen_s = time.perf_counter() - t0
     items = {}
     prepared = []
     requests_items = []
@@ -321,19 +361,11 @@ def run_cell(argv=None, allow_cpu=False, wrap_check=None, shrink=None):
             ids.append((r, j))
         requests_items.append(ids)
         prepared.append(entry.prepare(cell.config, req))
-    shape = cell.traffic["shape"]
-
-    def ops_events(req):
-        hists = req if shape == "single_key" else [
-            h for _k, h in req[0]]
-        return (sum(gen.client_ops(h) for h in hists),
-                sum(len(h) for h in hists))
-
     check = entry.check if wrap_check is None else wrap_check(entry.check)
     gc_total = [0.0]
 
     def one(r, record):
-        req = Request(r, *ops_events(pool[r]))
+        req = Request(r, *gen.request_size(pool[r], cell.traffic))
         u0 = usage(gc_total)
         req.mono0 = time.monotonic_ns()
         req.t0 = time.perf_counter()
@@ -425,6 +457,7 @@ def run_cell(argv=None, allow_cpu=False, wrap_check=None, shrink=None):
     run.window_compiles = counter.compiles - c0
     if args.trace:
         run.spans = [s.to_dict() for s in obs.tracer().finished()]
+        run.spans_dropped = obs.tracer().dropped
         run.obs_metrics = obs.registry().snapshot()
         obs.disable()
     peak = peak_bytes(devices)
@@ -433,7 +466,7 @@ def run_cell(argv=None, allow_cpu=False, wrap_check=None, shrink=None):
     trace_mod = load_module("xplane.py")
     if args.trace:
         run.profile = trace_mod.load(TRACE_DIR)
-    expected = verify.Expected(ref, gen, cell.config, shape)
+    expected = verify.Expected(ref, cell.config, cell.traffic)
     answered = [a for req in run.requests for a in req.answers]
     for req in run.requests:
         for item_id in requests_items[req.idx][len(req.answers):]:
@@ -460,6 +493,7 @@ def run_cell(argv=None, allow_cpu=False, wrap_check=None, shrink=None):
                                  walls[-1]] if walls else None,
                    "gc_s": sum(t for _g, t in gc_pauses),
                    "gc_gen2_s": [t for g, t in gc_pauses if g == 2],
+                   "spans_dropped": run.spans_dropped,
                    "slow_requests": slow_requests(run.requests)},
         "verify": {"verdicts_compared": compared, "first_mismatches": first,
                    "reference_runs": expected.reference_runs,
@@ -470,8 +504,7 @@ def run_cell(argv=None, allow_cpu=False, wrap_check=None, shrink=None):
     wanted = cell.per_layer if args.trace else cell.end_to_end
     kind_dir = "metrics" if args.trace else "end_to_end"
     for m in wanted:
-        reader = load_module(kind_dir, m["name"] + ".py")
-        value = reader.read(run)
+        value = reader(kind_dir, m["name"]).read(run)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     device = {"platform": devices[0].platform, "kind": kind,
@@ -492,8 +525,9 @@ def run_cell(argv=None, allow_cpu=False, wrap_check=None, shrink=None):
 
 
 def host_spans(run, red):
-    """The program's obs spans and the harness's requests on the
-    trace's clock, named for the idle-gap breakdown."""
+    """The program's obs spans, every one, and the harness's requests on
+    the trace's clock, named for the idle-gap breakdown: a span by its
+    own name (``checker/split``), but for three the engine's."""
     traced = run.requests[:len(red["requests"])]
     offsets = sorted(t0 - req.mono0 for (t0, _e, _i), req
                      in zip(red["requests"], traced))
@@ -503,7 +537,7 @@ def host_spans(run, red):
     out = [(s, e, "request: harness and checker seam")
            for s, e, _ in red["requests"]]
     for s in run.spans:
-        if s.get("t1") is None or not s["name"].startswith("engine/"):
+        if s.get("t1") is None:
             continue
         out.append((s["t0"] + off, s["t1"] + off,
                     {"engine/pipeline": "engine host: encode, plan, settle",

@@ -13,5 +13,5 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 PERF = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # after the standard library, as in perf/run.py: a file added by name
 # never shadows a module that jax or the program imports
-sys.path += [PERF, os.path.join(PERF, "traffic"),
-             os.path.join(PERF, "reference"), os.path.join(PERF, "metrics")]
+sys.path += [PERF, os.path.join(PERF, "reference"),
+             os.path.join(PERF, "metrics")]

@@ -13,19 +13,18 @@ gets wrong, over the whole pool a run's window cycles through.
 
 import argparse
 import json
-import os
-import sys
 import time
 
 if __name__ == "__main__":
     import conftest  # noqa: F401 — the same paths as under pytest
 
 import cas_register as ref
+import harness
 import pytest
+import run as perf_run
 
-from harness import run
-
-PERF = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: whole 1,000-op histories, so that each holds a crash or two
+WHOLE = {"cas1k_batch": {"ops_per_history": 1000}, "keyed_test": None}
 
 
 def control(check):
@@ -68,44 +67,61 @@ def reference_in_place(check):
     return sound
 
 
-@pytest.mark.parametrize("wl", ["cas1k_batch", "keyed_test"])
+@pytest.mark.parametrize("wl", sorted(WHOLE))
 def test_control_is_not_correct(wl):
-    # whole 1,000-op histories, so that each holds a crash or two
-    ops = None if wl == "keyed_test" else 1000
     for seed in (2**31 + 11, 2**31 + 12, 2**31 + 13):
-        res = run(wl, seed=seed, wrap=control, ops=ops)
+        res = harness.run(wl, seed=seed, wrap=control, config=WHOLE[wl])
         assert res["correct"] is False, res["checks"]
         assert res["checks"]["verdict_mismatches"]["value"] > 0
 
 
 @pytest.mark.parametrize("wl", ["cas1k_batch", "keyed_test"])
 def test_reference_in_place_is_correct(wl):
-    res = run(wl, wrap=reference_in_place)
+    res = harness.run(wl, wrap=reference_in_place)
     assert res["correct"] is True, res["checks"]
 
 
-def readings(workload, seed):
-    """Control mismatches over every verdict of the cell's pool."""
-    if PERF not in sys.path:
-        sys.path.append(PERF)
-    import run as perf_run
-
-    with open(os.path.join(os.path.dirname(PERF), "BENCHMARK.json")) as f:
-        cell = perf_run.Cell(json.load(f), workload)
-    gen = perf_run.load_module("traffic", "gen.py")
+def readings(workload, seed, shrunk=False, config=None):
+    """Control mismatches over every verdict of the cell's pool, at the
+    test size if ``shrunk``, with ``config`` merged over its
+    configuration."""
+    cell = harness.cell(workload, shrunk, config)
+    ref = cell.module("reference")
     verify = perf_run.load_module("verify.py")
-    pool = gen.make_pool(cell.config, cell.traffic, seed)
-    shape = cell.traffic["shape"]
+    pool = cell.module("generator").make_pool(cell.config, cell.traffic,
+                                              seed)
     items = {(r, j): it for r, req in enumerate(pool)
              for j, it in enumerate(req)}
-    want = verify.Expected(ref, gen, cell.config, shape)
-    got = verify.Expected(ref, gen, cell.config, shape,
+    want = verify.Expected(ref, cell.config, cell.traffic)
+    got = verify.Expected(ref, cell.config, cell.traffic,
                           crashed_take_effect=False)
     answers = [(i, got.of(i, it)) for i, it in items.items()]
     mismatches, compared, _first = verify.compare(want, items, answers,
                                                   set())
     return {"workload": workload, "seed": seed, "mismatches": mismatches,
             "compared": compared}
+
+
+#: (mismatches, verdicts compared) of the control as it read while the
+#: CAS-register generator and labels were the harness's own, at the test
+#: size with whole histories and at full size
+CONTROL_PINNED = {
+    ("cas1k_batch", "test_size"): {2**31 + 5: (11, 96), 2**31 + 11: (10, 96),
+                                   3000000019: (9, 96)},
+    ("keyed_test", "test_size"): {2**31 + 5: (0, 72), 2**31 + 11: (4, 72),
+                                  3000000019: (3, 72)},
+    ("cas1k_batch", "full"): {2400002611: (249, 2048)},
+    ("keyed_test", "full"): {2400002611: (745, 19213)},
+}
+
+
+@pytest.mark.parametrize("wl,size", sorted(CONTROL_PINNED))
+def test_control_readings_are_pinned(wl, size):
+    shrunk = size == "test_size"
+    for seed, (mismatches, compared) in CONTROL_PINNED[wl, size].items():
+        row = readings(wl, seed, shrunk, WHOLE[wl] if shrunk else None)
+        assert (row["mismatches"], row["compared"]) == (mismatches,
+                                                        compared), seed
 
 
 if __name__ == "__main__":

@@ -5,7 +5,9 @@ import itertools
 import random
 
 import cas_register as ref
-import gen
+import harness
+
+gen = harness.cell("cas1k_batch").module("generator")
 
 
 def brute_force(history, init=0):
@@ -75,7 +77,7 @@ def test_agrees_with_known_verdicts():
         h = gen.single_history(rng, 5, 200, rng.choice([0.002, 0.01, 0.05]),
                                rng.random() < 0.5, 5,
                                replace_crashed=rng.random() < 0.5)
-        known = gen.known_verdict(h, 5)
+        known = ref.known_verdict(h, 5)
         if known is not None:
             assert ref.linearizable(h) == known
             n += 1
@@ -87,10 +89,35 @@ def test_keyed_merges_per_key():
 
     keys = [(0, [("invoke", 0, "write", 1), ("ok", 0, "write", 1)]),
             (1, [("invoke", 1, "read", None), ("ok", 1, "read", 8)])]
-    want = verify.Expected(ref, gen, {"values": 5, "init": 0}, "keyed")
+    want = verify.Expected(ref, {"values": 5, "init": 0},
+                           {"shape": "keyed"})
     assert want.of("t", keys, audit=True) == {
         ("key", 0): True, ("key", 1): False, "valid?": False}
-    assert want.reference_runs == 2 and want.disagreements == 0
+    assert want.reference_runs == 3 and want.disagreements == 0
+
+
+def test_audit_is_counted_by_the_harness():
+    """verify.py, not the reference, holds the search to what is known:
+    a search that contradicts a known verdict counts on audit items,
+    is not asked where the verdict is known, and the control's search
+    is held to nothing."""
+    import types
+
+    import verify
+
+    liar = types.SimpleNamespace(
+        known=lambda cfg, tr, item: {"valid?": True, "other": None},
+        search=lambda cfg, tr, item, labels, **_o: {lb: False
+                                                    for lb in labels})
+    want = verify.Expected(liar, {}, {})
+    assert want.of("a", None) == {"valid?": True, "other": False}
+    assert want.reference_runs == 1 and want.disagreements == 0
+    assert want.of("b", None, audit=True) == {"valid?": False,
+                                              "other": False}
+    assert want.reference_runs == 3 and want.disagreements == 1
+    control = verify.Expected(liar, {}, {}, crashed_take_effect=False)
+    control.of("c", None)
+    assert control.reference_runs == 2 and control.disagreements == 0
 
 
 def test_crashed_ops_stay_open():

@@ -74,3 +74,20 @@ def test_recorded_chip_trace():
     assert busy <= dense_seconds(NS(profile=red)) <= window
     gaps = perf_trace.idle_gaps(red, [])
     assert sum(g for _n, g in gaps) <= window - busy + 1e-9
+
+
+def test_host_spans_name_every_program_span():
+    """Every finished span of the program names the gaps it covers, by
+    its own name but for the engine's three; the requests go first."""
+    import run as perf_run
+
+    run = NS(requests=[NS(mono0=0)], spans=[
+        {"name": "checker/split", "t0": 10, "t1": 50},
+        {"name": "engine/pipeline", "t0": 50, "t1": 90},
+        {"name": "engine/encode", "t0": 60, "t1": 70},
+        {"name": "engine/dispatch", "t0": 80, "t1": None}])
+    spans = perf_run.host_spans(run, {"requests": [(100, 200, 0)]})
+    assert spans == [(100, 200, "request: harness and checker seam"),
+                     (110, 150, "checker/split"),
+                     (150, 190, "engine host: encode, plan, settle"),
+                     (160, 170, "engine/encode")]

@@ -1,13 +1,17 @@
 """The comparison that decides ``correct``.
 
 Every verdict a window request returned is compared with what the
-plain reference expects for its item: the generator's known verdict
-where it has one (an honest history linearizes at its completions; a
-read of a value nobody wrote cannot), and the reference's search
-where it has none.  The reference also runs on a sample of items drawn
-from the seed, where its verdict must agree with the known one: that
-checks the yardstick itself.  A verdict that is missing, extra, or not
-a boolean counts as a mismatch.
+plain reference expects for its item.  The configuration's reference
+module (``perf/reference/<reference>.py``) answers two questions:
+``known(cfg, traffic, item)`` gives every label of the item with the
+verdict the generator knows for it, or None where it knows none, and
+``search(cfg, traffic, item, labels, **options)`` decides the labels
+asked for by its own search.  This module takes a known verdict as it
+stands and has the search decide the rest.  On a sample of items drawn
+from the seed (``audit``) it has the search decide every label and
+counts each one that disagrees with a known verdict: that checks the
+yardstick itself.  A verdict that is missing, extra, or not a boolean
+counts as a mismatch.
 """
 
 from __future__ import annotations
@@ -16,39 +20,40 @@ import random
 
 
 class Expected:
-    """Reference verdicts per pool item, computed once each."""
+    """Reference verdicts per pool item, computed once each.
 
-    def __init__(self, ref, gen, cfg, shape, crashed_take_effect=True):
+    ``options`` go to the reference's ``search`` (the control's): with
+    any, the search decides every label and nothing known is used or
+    held against it."""
+
+    def __init__(self, ref, cfg, traffic, **options):
         self.ref = ref
-        self.gen = gen
         self.cfg = cfg
-        self.shape = shape
-        self.crashed_take_effect = crashed_take_effect
+        self.traffic = traffic
+        self.options = options
         self.cache = {}
-        self.reference_runs = 0
-        self.disagreements = 0
-
-    def _history(self, hist, audit):
-        known = self.gen.known_verdict(hist, self.cfg["values"])
-        if known is None or audit or not self.crashed_take_effect:
-            self.reference_runs += 1
-            got = self.ref.linearizable(hist, self.cfg["init"],
-                                        self.crashed_take_effect)
-            if known is not None and got != known \
-                    and self.crashed_take_effect:
-                self.disagreements += 1
-            return got
-        return known
+        self.reference_runs = 0     # labels the search decided
+        self.disagreements = 0      # of them, those against a known one
 
     def of(self, item_id, item, audit=False):
         """{label: verdict} for one item, in the entry's labels."""
         if item_id in self.cache:
             return self.cache[item_id]
-        if self.shape == "single_key":
-            out = {"valid?": self._history(item, audit)}
+        known = self.ref.known(self.cfg, self.traffic, item)
+        if audit or self.options:
+            need = set(known)
         else:
-            out = {("key", k): self._history(h, audit) for k, h in item}
-            out["valid?"] = all(out.values())
+            need = {label for label, v in known.items() if v is None}
+        out = dict(known)
+        if need:
+            found = self.ref.search(self.cfg, self.traffic, item, need,
+                                    **self.options)
+            self.reference_runs += len(need)
+            for label in need:
+                out[label] = found[label]
+                if not self.options and known[label] is not None \
+                        and found[label] != known[label]:
+                    self.disagreements += 1
         self.cache[item_id] = out
         return out
 
