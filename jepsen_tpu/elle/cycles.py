@@ -18,7 +18,7 @@ but not plain serializability).
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Set
+from typing import Any, Dict, List, Optional, Set, Tuple
 
 from .graph import (
     Graph,
@@ -55,202 +55,236 @@ def _suffix(rels_used: Set[str]) -> str:
     return ""
 
 
-def classify(g: Graph, screen: Optional["GraphScreen"] = None
-             ) -> Dict[str, list]:
+def _induced(g: Graph, scc: List[Any]) -> Graph:
+    """The subgraph of ``g`` on one SCC's vertices, with every edge
+    between two of them in ``g``'s own successor order: a search over
+    it visits vertices exactly as a search over ``g`` that skips
+    non-members does."""
+    h = Graph()
+    members = set(scc)
+    h.vertices = members
+    for a in scc:
+        kept = {b: rels for b, rels in g.out.get(a, {}).items()
+                if b in members}
+        if kept:
+            h.out[a] = kept
+    return h
+
+
+def scc_parts(g: Graph) -> List[Tuple[List[Any], Graph]]:
+    """Tarjan over the union of ``g``'s edges: each nontrivial SCC
+    with its induced subgraph.  Any cycle, under any relation filter,
+    lies inside one SCC of the union, so every search (and every
+    device screen) can run on the SCCs alone."""
+    return [(scc, _induced(g, scc))
+            for scc in strongly_connected_components(g)]
+
+
+def classify(g: Graph, screens: Optional[list] = None,
+             parts: Optional[list] = None) -> Dict[str, list]:
     """Find one witness cycle per anomaly type per SCC.
 
-    With a ``screen`` (the device's per-relation-filter SCC membership
-    masks and nonadjacent-rw walk masks — :func:`screen_for_graphs`),
-    every ladder rung the device has proven empty *under that rung's
-    relation filter* is skipped outright: a skipped search is one the
-    CPU would provably have answered None, so the output is
-    byte-identical to the unscreened run (the fuzz corpus pins it) —
-    Tarjan and the BFS witness searches only run on graphs, and
-    rungs, already proven cyclic."""
-    from . import encode as encode_mod
-
+    ``parts`` is :func:`scc_parts` of ``g`` (computed here if not
+    given).  With ``screens`` (one per part: the device's
+    per-relation-filter cycle membership masks and nonadjacent-rw walk
+    masks of that SCC's subgraph, :func:`screen_for_graphs`, or
+    ``None`` for an SCC it did not screen), every ladder rung the
+    device has proven empty *under that rung's relation filter* is
+    skipped outright: a skipped search is one the CPU would provably
+    have answered None, so the output is byte-identical to the
+    unscreened run (the fuzz corpus pins it) — the BFS witness
+    searches only run on rungs already proven cyclic."""
+    if parts is None:
+        parts = scc_parts(g)
     anomalies: Dict[str, list] = {}
+    for i, (scc, h) in enumerate(parts):
+        _classify_scc(h, scc, None if screens is None else screens[i],
+                      anomalies)
+    return anomalies
+
+
+def _on_cycles(sub: Graph, scc: List[Any]) -> List[Any]:
+    """The vertices of ``scc`` that lie on some cycle of ``sub``, in
+    ``scc``'s order.  A cycle of ``sub`` passes through none of the
+    others, nor does any path that a BFS for one can still close, so
+    :func:`find_cycle` and :func:`find_cycle_with` over these alone
+    return exactly what they return over all of ``scc``, without the
+    searches from starts that cannot close."""
+    on: Set[Any] = set()
+    for comp in strongly_connected_components(sub):
+        on.update(comp)
+    return [v for v in scc if v in on]
+
+
+def _classify_scc(g: Graph, scc: List[Any], screen, anomalies) -> None:
+    """The anomaly ladder over one SCC ``scc`` whose induced subgraph
+    is ``g``, recording at most one cycle into ``anomalies``."""
+    from . import encode as encode_mod
 
     def record(name: str, cyc: List[Any]) -> None:
         anomalies.setdefault(name, []).append(_fmt_cycle(g, cyc))
 
-    if screen is not None:
-        full = screen.members(encode_mod.ALL_MASK)
-        if full is not None and not full:
-            # no vertex sits on any cycle at all: no nontrivial SCCs,
-            # so the whole classify pass (Tarjan included) is free
-            return anomalies
+    def rung_empty(mask: int) -> bool:
+        """Device-proven: this SCC has no cycle in the subgraph of
+        edges carrying a relation in ``mask``."""
+        if screen is None:
+            return False
+        mem = screen.members(mask)
+        return mem is not None and not mem
 
-    for scc in strongly_connected_components(g):
-        def rung_empty(mask: int) -> bool:
-            """Device-proven: this SCC has no cycle in the subgraph of
-            edges carrying a relation in ``mask``."""
-            if screen is None:
-                return False
-            mem = screen.members(mask)
-            return mem is not None and not any(v in mem for v in scc)
+    def walk_empty(rest_mask: int) -> bool:
+        """Device-proven: no nonadjacent-rw closed walk through any
+        vertex of this SCC (⇒ find_nonadjacent_cycle's walk BFS would
+        see nothing and answer None)."""
+        if screen is None:
+            return False
+        w = screen.nonadj(encode_mod.RW_BIT, rest_mask)
+        return w is not None and not w
 
-        def walk_empty(rest_mask: int) -> bool:
-            """Device-proven: no nonadjacent-rw closed walk through
-            any vertex of this SCC (⇒ find_nonadjacent_cycle's walk
-            BFS would see nothing and answer None)."""
-            if screen is None:
-                return False
-            w = screen.nonadj(encode_mod.RW_BIT, rest_mask)
-            return w is not None and not any(v in w for v in scc)
+    # Most-severe-first: G0, then G1c, then G-single, then G2-item.
+    has_rw = lambda rels: RW in rels  # noqa: E731
 
-        # Most-severe-first: G0, then G1c, then G-single, then G2-item.
-        ww_only = lambda rels: rels <= {WW}  # noqa: E731
-        ww_wr = lambda rels: bool(rels & {WW, WR}) and not (rels & {RW})  # noqa: E731
-        has_rw = lambda rels: RW in rels  # noqa: E731
+    if rung_empty(encode_mod.WW_BIT):
+        cyc = None
+    else:
+        sub = g.filtered(lambda rels: bool(rels & {WW}))
+        cyc = find_cycle(sub, _on_cycles(sub, scc))
+    if cyc is not None:
+        record("G0", cyc)
+        return
 
-        if rung_empty(encode_mod.WW_BIT):
-            cyc = None
-        else:
-            sub = g.filtered(lambda rels: bool(rels & {WW}))
-            cyc = find_cycle(sub, [v for v in scc if v in sub.vertices])
-        if cyc is not None:
-            record("G0", cyc)
-            continue
+    if rung_empty(encode_mod.WW_BIT | encode_mod.WR_BIT):
+        cyc = None
+    else:
+        sub = g.filtered(lambda rels: bool(rels & {WW, WR}))
+        cyc = find_cycle(sub, _on_cycles(sub, scc))
+    if cyc is not None:
+        record("G1c", cyc)
+        return
 
-        if rung_empty(encode_mod.WW_BIT | encode_mod.WR_BIT):
-            cyc = None
-        else:
-            sub = g.filtered(lambda rels: bool(rels & {WW, WR}))
-            cyc = find_cycle(sub, [v for v in scc if v in sub.vertices])
-        if cyc is not None:
-            record("G1c", cyc)
-            continue
+    # every remaining plain rung needs a cycle inside the ww|wr|rw
+    # subgraph; one device mask screens all three, and where none did
+    # (an SCC past the device's cap), the host's Tarjan over it does
+    rw_rungs_empty = rung_empty(
+        encode_mod.WW_BIT | encode_mod.WR_BIT | encode_mod.RW_BIT
+    )
+    if not rw_rungs_empty:
+        plain = g.filtered(lambda rels: bool(rels & {WW, WR, RW}))
+        plain_on = _on_cycles(plain, scc)
+        rw_rungs_empty = not plain_on
 
-        # every remaining plain rung needs a cycle inside the
-        # ww|wr|rw subgraph; one device mask screens all three
-        rw_rungs_empty = rung_empty(
-            encode_mod.WW_BIT | encode_mod.WR_BIT | encode_mod.RW_BIT
-        )
+    cyc = None if rw_rungs_empty else find_cycle_with(
+        g,
+        plain_on,
+        want=has_rw,
+        rest=lambda rels: bool(rels & {WW, WR}),
+        want_count=1,
+    )
+    if cyc is not None:
+        record("G-single", cyc)
+        return
 
-        cyc = None if rw_rungs_empty else find_cycle_with(
+    # G-nonadjacent: ≥2 rw edges, none cyclically adjacent — still a
+    # snapshot-isolation violation (SI cycles need two adjacent rws)
+    if rw_rungs_empty or walk_empty(encode_mod.WW_BIT | encode_mod.WR_BIT):
+        cyc = None
+    else:
+        cyc = find_nonadjacent_cycle(
             g,
             scc,
             want=has_rw,
             rest=lambda rels: bool(rels & {WW, WR}),
-            want_count=1,
         )
-        if cyc is not None:
-            record("G-single", cyc)
-            continue
+    if cyc is INDETERMINATE:
+        # simple-cycle search budget exhausted: a G-nonadjacent may
+        # exist in this SCC.  Record the uncertainty (result() turns
+        # it into valid?=unknown for models that proscribe the
+        # anomaly) and fall through to the definite G2-item witness.
+        anomalies.setdefault("G-nonadjacent-indeterminate", []).append(
+            {
+                "scc-size": len(scc),
+                "reason": "simple-cycle search budget exhausted",
+            }
+        )
+    elif cyc is not None:
+        record("G-nonadjacent", cyc)
+        return
 
-        # G-nonadjacent: ≥2 rw edges, none cyclically adjacent — still a
-        # snapshot-isolation violation (SI cycles need two adjacent rws)
-        if rw_rungs_empty or walk_empty(
-            encode_mod.WW_BIT | encode_mod.WR_BIT
-        ):
-            cyc = None
-        else:
-            cyc = find_nonadjacent_cycle(
-                g,
-                scc,
-                want=has_rw,
-                rest=lambda rels: bool(rels & {WW, WR}),
-            )
-        if cyc is INDETERMINATE:
-            # simple-cycle search budget exhausted: a G-nonadjacent may
-            # exist in this SCC.  Record the uncertainty (result() turns
-            # it into valid?=unknown for models that proscribe the
-            # anomaly) and fall through to the definite G2-item witness.
-            anomalies.setdefault("G-nonadjacent-indeterminate", []).append(
-                {
-                    "scc-size": len(scc),
-                    "reason": "simple-cycle search budget exhausted",
-                }
-            )
-        elif cyc is not None:
-            record("G-nonadjacent", cyc)
-            continue
+    cyc = None if rw_rungs_empty else find_cycle(plain, plain_on)
+    if cyc is not None:
+        record("G2-item", cyc)
+        return
 
-        if rw_rungs_empty:
-            cyc = None
-        else:
-            sub = g.filtered(lambda rels: bool(rels & {WW, WR, RW}))
-            cyc = find_cycle(sub, [v for v in scc if v in sub.vertices])
-        if cyc is not None:
-            record("G2-item", cyc)
-            continue
-
-        # Cycle requires process/realtime edges: -realtime/-process
-        # variants of the same ladder.
-        pr = encode_mod.PR_MASK
-        for want_rels, name in (
-            ({WW}, "G0"),
-            ({WW, WR}, "G1c"),
-            (None, "G-single"),
-            ("nonadjacent", "G-nonadjacent"),
-            ({WW, WR, RW}, "G2-item"),
-        ):
-            if name == "G-single":
-                cyc = None if rung_empty(encode_mod.ALL_MASK) else (
-                    find_cycle_with(
-                        g,
-                        scc,
-                        want=has_rw,
-                        rest=lambda rels: bool(
-                            rels & {WW, WR, PROCESS, REALTIME}
-                        ),
-                        want_count=1,
-                    )
+    # Cycle requires process/realtime edges: -realtime/-process
+    # variants of the same ladder.
+    pr = encode_mod.PR_MASK
+    for want_rels, name in (
+        ({WW}, "G0"),
+        ({WW, WR}, "G1c"),
+        (None, "G-single"),
+        ("nonadjacent", "G-nonadjacent"),
+        ({WW, WR, RW}, "G2-item"),
+    ):
+        if name == "G-single":
+            cyc = None if rung_empty(encode_mod.ALL_MASK) else (
+                find_cycle_with(
+                    g,
+                    scc,
+                    want=has_rw,
+                    rest=lambda rels: bool(
+                        rels & {WW, WR, PROCESS, REALTIME}
+                    ),
+                    want_count=1,
                 )
-            elif name == "G-nonadjacent":
-                cyc = (
-                    None
-                    if walk_empty(encode_mod.WW_BIT | encode_mod.WR_BIT | pr)
-                    else find_nonadjacent_cycle(
-                        g,
-                        scc,
-                        want=has_rw,
-                        rest=lambda rels: bool(
-                            rels & {WW, WR, PROCESS, REALTIME}
-                        ),
-                    )
+            )
+        elif name == "G-nonadjacent":
+            cyc = (
+                None
+                if walk_empty(encode_mod.WW_BIT | encode_mod.WR_BIT | pr)
+                else find_nonadjacent_cycle(
+                    g,
+                    scc,
+                    want=has_rw,
+                    rest=lambda rels: bool(
+                        rels & {WW, WR, PROCESS, REALTIME}
+                    ),
                 )
-                if cyc is INDETERMINATE:
-                    # this rung's hypothetical cycle needs process or
-                    # realtime edges (the plain rung already answered
-                    # definitively or recorded its own marker), so only
-                    # the suffixed variants are uncertain — the plain
-                    # marker would wrongly degrade serializable/SI
-                    # verdicts that are provably clean
-                    for suffixed in (
-                        "G-nonadjacent-process-indeterminate",
-                        "G-nonadjacent-realtime-indeterminate",
-                    ):
-                        anomalies.setdefault(suffixed, []).append(
-                            {
-                                "scc-size": len(scc),
-                                "reason": (
-                                    "simple-cycle search budget exhausted"
-                                ),
-                            }
-                        )
-                    cyc = None
+            )
+            if cyc is INDETERMINATE:
+                # this rung's hypothetical cycle needs process or
+                # realtime edges (the plain rung already answered
+                # definitively or recorded its own marker), so only
+                # the suffixed variants are uncertain — the plain
+                # marker would wrongly degrade serializable/SI
+                # verdicts that are provably clean
+                for suffixed in (
+                    "G-nonadjacent-process-indeterminate",
+                    "G-nonadjacent-realtime-indeterminate",
+                ):
+                    anomalies.setdefault(suffixed, []).append(
+                        {
+                            "scc-size": len(scc),
+                            "reason": "simple-cycle search budget exhausted",
+                        }
+                    )
+                cyc = None
+        else:
+            mask = encode_mod.rel_mask(want_rels) | pr
+            if rung_empty(mask):
+                cyc = None
             else:
-                mask = encode_mod.rel_mask(want_rels) | pr
-                if rung_empty(mask):
-                    cyc = None
-                else:
-                    sub = g.filtered(
-                        lambda rels, wr=want_rels: bool(
-                            rels & (wr | {PROCESS, REALTIME})
-                        )
+                sub = g.filtered(
+                    lambda rels, wr=want_rels: bool(
+                        rels & (wr | {PROCESS, REALTIME})
                     )
-                    cyc = find_cycle(
-                        sub, [v for v in scc if v in sub.vertices]
-                    )
-            if cyc is not None:
-                used: Set[str] = set()
-                for rels in cycle_rels(g, cyc):
-                    used |= rels
-                record(name + _suffix(used), cyc)
-                break
-    return anomalies
+                )
+                cyc = find_cycle(sub, _on_cycles(sub, scc))
+        if cyc is not None:
+            used: Set[str] = set()
+            for rels in cycle_rels(g, cyc):
+                used |= rels
+            record(name + _suffix(used), cyc)
+            return
 
 
 #: winner cache for the device-vs-CPU cycle screen, keyed by
@@ -266,7 +300,8 @@ _SCREEN_CHOICE: dict = {}
 #: there (1.6× at n=256, benchmarks/elle_results_tpu.json, 2026-07-31),
 #: which is why the cap sits at 512
 #: and not lower — and a first-touch calibration on a huge padded
-#: matrix would burn minutes proving the obvious
+#: matrix would burn minutes proving the obvious.  classify_graphs
+#: applies it per SCC of a graph's union, not to the whole graph
 DEVICE_SCREEN_MAX_VERTICES = 512
 
 
@@ -362,12 +397,12 @@ def cyclic_graph_mask(graphs: List[Graph], use_device: Optional[bool] = None):
 # the production engine (ops.cycles → engine.execution.Executor)
 # ---------------------------------------------------------------------------
 
-#: winner cache for the screened-vs-CPU classify router, keyed like
-#: _SCREEN_CHOICE by (vertex-bucket, batch-size-bucket); "cpu" is the
+#: winner cache for the screened-vs-CPU classify router, keyed by the
+#: SCC batch's (vertex-bucket, batch-size-bucket); "cpu" is the
 #: terminal state after any device error or cross-check mismatch
 _CLASSIFY_CHOICE: dict = {}
 
-#: below this many screenable graphs the auto route stays on CPU —
+#: below this many screenable SCCs the auto route stays on CPU —
 #: dispatch overhead says nothing useful about tiny batches (the same
 #: ≥16 gate version_graphs applies to its cycle screen)
 ELLE_SCREEN_MIN_BATCH = 16
@@ -425,7 +460,7 @@ class GraphScreen:
         return got
 
 
-def screen_for_graphs(graphs: List[Graph], executor=None):
+def screen_for_graphs(graphs: List[Graph], executor=None, encs=None):
     """Encode and screen a batch of dependency graphs through the
     engine: returns ``(screens, route)`` with one
     :class:`GraphScreen` (or ``None`` — CPU fallback for that graph)
@@ -434,11 +469,13 @@ def screen_for_graphs(graphs: List[Graph], executor=None):
     with concurrent runs on the daemon's resident executor; otherwise
     they dispatch through an in-process
     :class:`~jepsen_tpu.engine.execution.Executor` (window, per-chip
-    budget, mesh)."""
+    budget, mesh).  ``encs``: the graphs already encoded
+    (:func:`~jepsen_tpu.elle.encode.encode_graph`)."""
     from . import encode as encode_mod
     from ..ops import cycles as ops_cycles
 
-    encs = [encode_mod.encode_graph(g) for g in graphs]
+    if encs is None:
+        encs = [encode_mod.encode_graph(g) for g in graphs]
     results = None
     route = "device"
     if executor is None:
@@ -461,39 +498,92 @@ def screen_for_graphs(graphs: List[Graph], executor=None):
     return screens, route
 
 
-def _classify_screened(graphs: List[Graph], executor=None,
-                       count: bool = True) -> List[Dict[str, list]]:
-    """Classify with device screens, recording the route and the
-    witness-search fallback evidence per graph.  ``count=False``
-    suppresses the counters — the calibration probes run this path
-    without *serving* its results, and served-route accounting must
-    reflect what callers actually received."""
+def _screen_parts(parts_of: List[list], executor=None):
+    """Screen every SCC of ``parts_of`` (per graph, its
+    :func:`scc_parts`) that fits :data:`DEVICE_SCREEN_MAX_VERTICES`,
+    pooled into one :func:`screen_for_graphs` batch.  Returns per graph
+    the list of its SCCs' screens (``None`` where an SCC stayed on the
+    CPU), and the route the batch took."""
     from . import encode as encode_mod
     from .. import obs
 
-    screens, route = screen_for_graphs(graphs, executor=executor)
-    out = []
-    n_screened = n_fallback = n_cpu = 0
-    for g, s in zip(graphs, screens):
-        if s is None:
-            n_cpu += 1
-            out.append(classify(g))
-            continue
-        n_screened += 1
-        full = s.members(encode_mod.ALL_MASK)
-        if full:
-            # the screen proved a cycle exists: CPU Tarjan + witness
-            # search still runs for this graph — the measured
-            # "witness-search fallback" fraction of the bench headline
-            n_fallback += 1
-        out.append(classify(g, s))
-    if count and obs.enabled():
-        if n_screened:
-            obs.count("jepsen_elle_screen_route_total", n_screened,
-                      route=route)
-        if n_cpu:
-            obs.count("jepsen_elle_screen_route_total", n_cpu,
-                      route="cpu")
+    screens: List[list] = [[None] * len(p) for p in parts_of]
+    where = [(gi, si) for gi, p in enumerate(parts_of)
+             for si, (scc, _h) in enumerate(p)
+             if len(scc) <= DEVICE_SCREEN_MAX_VERTICES]
+    route = "device"
+    if where:
+        subs = [parts_of[gi][si][1] for gi, si in where]
+        with obs.span("elle/screen", cat="elle") as sp:
+            encs = [encode_mod.encode_graph(h) for h in subs]
+            got, route = screen_for_graphs(subs, executor=executor,
+                                           encs=encs)
+            if sp:
+                # the workload, per SCC: live vertices, filter planes
+                # and nonadjacent walk queries it asked the screen for
+                sp.set("scc_vertices", ",".join(str(e.n) for e in encs))
+                sp.set("planes", ",".join(str(len(e.masks)) for e in encs))
+                sp.set("walks", ",".join(str(len(e.nonadj)) for e in encs))
+        for (gi, si), s in zip(where, got):
+            screens[gi][si] = s
+    return screens, route
+
+
+def _count_routes(parts_of: List[list], screens: Optional[List[list]] = None,
+                  route: str = "cpu") -> None:
+    """Route counters of the graphs served: each SCC by where it was
+    screened (``cpu``: searched unscreened), and each graph ``cpu`` if
+    any of its SCCs was, else by ``route`` (a graph with no nontrivial
+    SCC needs no screen)."""
+    from .. import obs
+
+    if not obs.enabled():
+        return
+    by_route: Dict[str, int] = {}
+    for i, p in enumerate(parts_of):
+        ss = [None] * len(p) if screens is None else screens[i]
+        for (scc, _h), s in zip(p, ss):
+            r = "cpu" if s is None else route
+            obs.count("jepsen_elle_scc_total", 1, route=r)
+            obs.count("jepsen_elle_scc_vertices_total", len(scc), route=r)
+        r = "cpu" if any(s is None for s in ss) else route
+        by_route[r] = by_route.get(r, 0) + 1
+    for r, k in by_route.items():
+        obs.count("jepsen_elle_screen_route_total", k, route=r)
+
+
+def _search(graphs: List[Graph], parts_of: List[list],
+            screens: Optional[List[list]] = None) -> List[Dict[str, list]]:
+    """:func:`classify` over every graph, under one ``elle/search``
+    span: the witness searches the screens did not rule out."""
+    from .. import obs
+
+    with obs.span("elle/search", cat="elle"):
+        return [classify(g, None if screens is None else screens[i], p)
+                for i, (g, p) in enumerate(zip(graphs, parts_of))]
+
+
+def _classify_screened(graphs: List[Graph], executor=None,
+                       count: bool = True,
+                       parts_of: Optional[List[list]] = None
+                       ) -> List[Dict[str, list]]:
+    """Classify with device screens of every SCC that fits the cap,
+    recording the routes and the witness-search fallback evidence.
+    ``count=False`` suppresses the counters — the calibration probes
+    run this path without *serving* its results, and served-route
+    accounting must reflect what callers actually received."""
+    from .. import obs
+
+    if parts_of is None:
+        parts_of = [scc_parts(g) for g in graphs]
+    screens, route = _screen_parts(parts_of, executor)
+    out = _search(graphs, parts_of, screens)
+    if count:
+        _count_routes(parts_of, screens, route)
+        # a graph whose union has a cycle still pays the witness
+        # search — the measured "witness-search fallback" fraction of
+        # the bench headline
+        n_fallback = sum(1 for p in parts_of if p)
         if n_fallback:
             obs.count("jepsen_elle_witness_fallback_total", n_fallback)
     return out
@@ -510,88 +600,66 @@ def classify_graphs(
     route: Optional[str] = None,
     executor=None,
 ) -> List[Dict[str, list]]:
-    """Batched :func:`classify`: screen every graph's relation-filter
-    cycle structure on the device in shared engine dispatches, then
-    pay CPU Tarjan + witness search only where the screens proved
-    cycles exist.  ``route``: ``"cpu"`` (pure host path — the
-    byte-identity reference), ``"device"`` (screens forced — smoke,
-    fuzz, bench), or ``None``/``"auto"`` (default; also
-    ``JEPSEN_TPU_ELLE_SCREEN``): SELF-CALIBRATING per (vertex-bucket,
-    batch-bucket) pair exactly like :func:`cyclic_graph_mask` — the
-    first batch at each pair runs both paths, cross-checks anomalies
-    for equality, and pins the faster engine; a device error or
-    mismatch pins the pair to CPU permanently (the screens must never
-    trade correctness for speed).  Graphs past
-    :data:`DEVICE_SCREEN_MAX_VERTICES` (or below 2 vertices) always
-    classify on the CPU."""
+    """Batched :func:`classify`: one host Tarjan per graph over the
+    union of its edges, then every nontrivial SCC of up to
+    :data:`DEVICE_SCREEN_MAX_VERTICES` vertices, from all graphs of the
+    call, is screened on the device in shared engine dispatches, and
+    CPU witness search runs only on the rungs the screens proved
+    cyclic (and on every rung of an SCC past the cap).  A graph whose
+    union has no nontrivial SCC needs neither.  ``route``: ``"cpu"``
+    (pure host path — the byte-identity reference), ``"device"``
+    (screens forced — smoke, fuzz, bench), or ``None``/``"auto"``
+    (default; also ``JEPSEN_TPU_ELLE_SCREEN``): SELF-CALIBRATING per
+    (SCC vertex-bucket, SCC batch-bucket) pair exactly like
+    :func:`cyclic_graph_mask` — the first batch at each pair runs both
+    paths, cross-checks anomalies for equality, and pins the faster
+    engine; a device error or mismatch pins the pair to CPU
+    permanently (the screens must never trade correctness for
+    speed), and a batch of fewer than :data:`ELLE_SCREEN_MIN_BATCH`
+    screenable SCCs stays on the CPU."""
     import logging
     import time
 
     from .. import obs
 
     route = (route or _classify_route()).lower()
-    n = len(graphs)
-    if n == 0:
+    if not graphs:
         return []
-    if route == "cpu":
-        if obs.enabled():
-            obs.count("jepsen_elle_screen_route_total", n, route="cpu")
-        return [classify(g) for g in graphs]
-
-    screenable = [
-        i for i, g in enumerate(graphs)
-        if 2 <= len(g.vertices) <= DEVICE_SCREEN_MAX_VERTICES
-    ]
-    out: List[Optional[Dict[str, list]]] = [None] * n
-    rest = [i for i in set(range(n)) - set(screenable)]
-    for i in sorted(rest):
-        out[i] = classify(graphs[i])
-    if rest and obs.enabled():
-        obs.count("jepsen_elle_screen_route_total", len(rest), route="cpu")
-    sub = [graphs[i] for i in screenable]
-
+    with obs.span("elle/scc", cat="elle"):
+        parts_of = [scc_parts(g) for g in graphs]
     if route in ("device", "service"):
-        screened = _classify_screened(sub, executor=executor)
-        for i, r in zip(screenable, screened):
-            out[i] = r
-        return out  # type: ignore[return-value]
-
-    # auto: self-calibrating, with the small-batch gate
-    if len(sub) < ELLE_SCREEN_MIN_BATCH:
-        for i in screenable:
-            out[i] = classify(graphs[i])
-        if sub and obs.enabled():
-            obs.count("jepsen_elle_screen_route_total", len(sub),
-                      route="cpu")
-        return out  # type: ignore[return-value]
-    biggest = max(len(g.vertices) for g in sub)
-    key = (_screen_bucket(biggest), _screen_bucket(len(sub)))
+        return _classify_screened(graphs, executor=executor,
+                                  parts_of=parts_of)
+    sizes = [len(scc) for p in parts_of for scc, _h in p
+             if len(scc) <= DEVICE_SCREEN_MAX_VERTICES]
+    if route == "cpu" or len(sizes) < ELLE_SCREEN_MIN_BATCH:
+        # auto: dispatch overhead says nothing useful about tiny
+        # batches, which stay on the CPU
+        _count_routes(parts_of)
+        return _search(graphs, parts_of)
+    key = (_screen_bucket(max(sizes)), _screen_bucket(len(sizes)))
     choice = _CLASSIFY_CHOICE.get(key)
     if choice == "device":
-        screened = _classify_screened(sub, executor=executor)
-        for i, r in zip(screenable, screened):
-            out[i] = r
-        return out  # type: ignore[return-value]
+        return _classify_screened(graphs, executor=executor,
+                                  parts_of=parts_of)
     if choice == "cpu":
-        for i in screenable:
-            out[i] = classify(graphs[i])
-        if obs.enabled():
-            obs.count("jepsen_elle_screen_route_total", len(sub),
-                      route="cpu")
-        return out  # type: ignore[return-value]
+        _count_routes(parts_of)
+        return _search(graphs, parts_of)
 
     # calibrate: both engines classify this batch; the winner takes
     # the bucket pair, and a cross-check mismatch pins it to CPU —
     # correctness is never traded for speed.  A device error raises:
     # a kernel that fails on the chip must not pass as a CPU run
     t0 = time.perf_counter()
-    cpu_out = [classify(g) for g in sub]
+    cpu_out = _search(graphs, parts_of)
     t_cpu = time.perf_counter() - t0
     # count=False: these are probes — the CPU results below are what
     # the caller is served, so the route counter must say cpu
-    _classify_screened(sub, executor=executor, count=False)  # warm/compile
+    _classify_screened(graphs, executor=executor, count=False,
+                       parts_of=parts_of)  # warm/compile
     t0 = time.perf_counter()
-    dev_out = _classify_screened(sub, executor=executor, count=False)
+    dev_out = _classify_screened(graphs, executor=executor, count=False,
+                                 parts_of=parts_of)
     t_dev = time.perf_counter() - t0
     if dev_out != cpu_out:
         logging.getLogger(__name__).warning(
@@ -601,9 +669,6 @@ def classify_graphs(
         _CLASSIFY_CHOICE[key] = "cpu"
     else:
         _CLASSIFY_CHOICE[key] = "device" if t_dev < t_cpu else "cpu"
-    if obs.enabled():
-        # the calibration batch is SERVED the CPU answers
-        obs.count("jepsen_elle_screen_route_total", len(sub), route="cpu")
-    for i, r in zip(screenable, cpu_out):
-        out[i] = r
-    return out  # type: ignore[return-value]
+    # the calibration batch is SERVED the CPU answers
+    _count_routes(parts_of)
+    return cpu_out

@@ -68,15 +68,22 @@ def internal_cases(txns: List[Txn]) -> List[dict]:
     return cases
 
 
-def g1a_cases(txns: List[Txn]) -> List[dict]:
-    """Reads of values appended by failed txns."""
-    failed: Set[Tuple[Any, Any]] = {
+def failed_appends(txns: List[Txn]) -> Set[Tuple[Any, Any]]:
+    """Every ``(key, element)`` a failed txn tried to append."""
+    return {
         (k, v)
         for t in txns
         if t.failed
         for f, k, v in mops(t)
         if f == APPEND
     }
+
+
+def g1a_cases(txns: List[Txn],
+              failed: Optional[Set[Tuple[Any, Any]]] = None) -> List[dict]:
+    """Reads of values appended by failed txns."""
+    if failed is None:
+        failed = failed_appends(txns)
     cases = []
     for t in txns:
         if not t.ok:
@@ -190,6 +197,13 @@ def graph_and_anomalies(
     extra_graphs: Tuple[str, ...] = (),
 ) -> Tuple[Graph, List[Txn], Dict[str, list]]:
     """Build the dependency graph and collect non-cycle anomalies."""
+    from .. import obs
+
+    with obs.span("elle/infer", cat="elle"):
+        return _graph_and_anomalies(history, extra_graphs)
+
+
+def _graph_and_anomalies(history, extra_graphs):
     txns = core.transactions(history)
     anomalies: Dict[str, list] = {}
 
@@ -209,7 +223,8 @@ def graph_and_anomalies(
     internal = internal_cases(txns)
     if internal:
         anomalies["internal"] = internal
-    g1a = g1a_cases(txns)
+    failed = failed_appends(txns)
+    g1a = g1a_cases(txns, failed)
     if g1a:
         anomalies["G1a"] = g1a
     g1b = g1b_cases(txns, appends_by_txn)
@@ -266,20 +281,10 @@ def graph_and_anomalies(
     dirty = []
     for k, order in orders.items():
         for el in order:
-            w = writer_of.get((k, el))
-            if w is None:
-                # element read but not appended by any ok/info txn
-                failed_writers = [
-                    t
-                    for t in txns
-                    if t.failed
-                    and any(
-                        f == APPEND and kk == k and vv == el
-                        for f, kk, vv in mops(t)
-                    )
-                ]
-                if failed_writers:
-                    dirty.append({"key": k, "element": el})
+            # element read but not appended by any ok/info txn, and
+            # appended by a failed one
+            if (k, el) not in writer_of and (k, el) in failed:
+                dirty.append({"key": k, "element": el})
     if dirty:
         anomalies["dirty-update"] = dirty
 

@@ -478,7 +478,9 @@ def test_graph_screen_canonicalizes_absent_relations():
 def test_classify_graphs_auto_calibrates_and_pins_cpu_on_mismatch(
     monkeypatch,
 ):
-    graphs = [_rw_chain(9, i % 2 == 0) for i in range(20)]
+    # 40 graphs, 20 of them cyclic: the router keys on the batch of
+    # 20 nine-vertex SCCs, not on the graphs
+    graphs = [_rw_chain(9, i % 2 == 0) for i in range(40)]
     expected = [elle_cycles.classify(g) for g in graphs]
 
     monkeypatch.setattr(elle_cycles, "_CLASSIFY_CHOICE", {})
@@ -492,14 +494,14 @@ def test_classify_graphs_auto_calibrates_and_pins_cpu_on_mismatch(
     monkeypatch.setattr(elle_cycles, "_CLASSIFY_CHOICE", {})
     monkeypatch.setattr(
         elle_cycles, "_classify_screened",
-        lambda gs, executor=None, count=True: [{} for _ in gs],
+        lambda gs, executor=None, count=True, **_kw: [{} for _ in gs],
     )
     out = elle_cycles.classify_graphs(graphs)
     assert out == expected
     assert elle_cycles._CLASSIFY_CHOICE.get(key) == "cpu"
 
     # a crashing screen path raises instead of pinning the CPU
-    def boom(gs, executor=None, count=True):
+    def boom(gs, executor=None, count=True, **_kw):
         raise RuntimeError("no backend")
 
     monkeypatch.setattr(elle_cycles, "_CLASSIFY_CHOICE", {})
