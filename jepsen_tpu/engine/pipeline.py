@@ -75,9 +75,9 @@ Pipeline telemetry (obs registry; doc/observability.md):
   buckets one batch split into.
 
 Spans (category ``engine``) nest under ``engine/pipeline`` on its
-thread: ``engine/encode`` per history, ``engine/plan`` per flush,
-``engine/issue`` per dispatch, ``engine/dispatch`` per wait on one,
-``engine/settle`` per retired dispatch or escalation pass.
+thread: ``engine/encode`` per chunk of histories, ``engine/plan`` per
+flush, ``engine/issue`` per dispatch, ``engine/dispatch`` per wait on
+one, ``engine/settle`` per retired dispatch or escalation pass.
 """
 
 from __future__ import annotations

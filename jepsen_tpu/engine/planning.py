@@ -46,10 +46,6 @@ DEFAULT_FLUSH_ROWS = 16384
 
 _UNSET = object()
 
-#: sentinel distinct from every bucket key (``None`` is the legitimate
-#: key of unbucketed mode): this history routed to the oracle pool
-_ROUTED_ORACLE = object()
-
 
 def _note_settle_times(result: dict) -> None:
     """Record the run's time-to-first-verdict / time-to-violation
@@ -315,19 +311,17 @@ class Planner:
 
     # -- encoding ---------------------------------------------------------
 
-    def encode_one(self, ctx: RunContext, idx: int):
-        """Encode one history of ``ctx``; ``None`` routes it to the
-        oracle (unencodable — the caller's stage 3 starts NOW).  The
-        per-history model (``ctx.model_for``) seeds the init state —
-        decomposed sub-histories share this planner's spec family but
-        each carry their own partition's seeded sub-model."""
+    def chunk_full(self, rows: int, events: int) -> bool:
+        """Whether a pending chunk of ``rows`` histories holding
+        ``events`` events is due: :data:`encode.CHUNK_EVENTS
+        <jepsen_tpu.ops.encode.CHUNK_EVENTS>` events, or as many
+        histories as a flush.  A spec with no columnar encoder gains
+        nothing from a chunk: each of its histories is due at once."""
         from ..ops import encode as encode_mod
 
-        if self.spec is None:
-            return None
-        return encode_mod.encode_history(
-            ctx.histories[idx], ctx.model_for(idx), self.slot_cap, self.spec
-        )
+        return (self.spec is None or self.spec.encode_columns is None
+                or events >= encode_mod.CHUNK_EVENTS
+                or rows >= self.flush_rows)
 
     def bucket_key(self, e) -> Optional[tuple]:
         from ..ops import encode as encode_mod
@@ -336,46 +330,77 @@ class Planner:
             encode_mod.bucket_key(e, self.slot_cap) if self.bucketed else None
         )
 
-    def _accumulate(self, ctx: RunContext, idx: int, buckets, order):
-        """Encode one history into its bucket (the ONE shared
-        encode/route/accumulate step — oracle routing and bucket
-        keying cannot diverge between the in-process stream and the
-        service's encode_buckets).  Returns the bucket key the history
-        landed in (``None`` IS a valid key in unbucketed mode), or
-        :data:`_ROUTED_ORACLE` when it went to the oracle instead —
-        that search starts NOW, on the worker pool, overlapping all
-        remaining encode and device work.
+    def encode_chunk(self, rows: list) -> list:
+        """Encode a chunk of ``(ctx, idx)`` rows at once (the ONE
+        shared encode/route step — oracle routing and bucket keying
+        cannot diverge between the in-process stream and the service's
+        encode_buckets): ``[(token, key, encoded)]`` for the rows that
+        encoded, in row order; ``None`` IS a valid key in unbucketed
+        mode.  An unencodable row routes to the oracle as the chunk's
+        encode ends — that search starts NOW, on the worker pool,
+        overlapping all remaining encode and device work.  The
+        per-history model (``ctx.model_for``) seeds the init state —
+        decomposed sub-histories share this planner's spec family but
+        each carry their own partition's seeded sub-model.
 
-        A slot that already holds a verdict — WAL-replayed before
+        A row that already holds a verdict — WAL-replayed before
         encode — is skipped outright: settled rows never re-encode or
         re-dispatch, which is what makes a restarted run re-dispatch
         only its unsettled partitions."""
-        if ctx.settled(idx):
-            return _ROUTED_ORACLE
-        # the span covers the history's way into its bucket too
+        from ..ops import encode as encode_mod
+
+        rows = [(ctx, idx) for ctx, idx in rows if not ctx.settled(idx)]
+        if not rows:
+            return []
+        hists = [ctx.histories[idx] for ctx, idx in rows]
+        placed = []
+        # one span a chunk; its ``events`` are the encoded histories'
         with obs.span("engine/encode", cat="engine") as sp:
-            e = self.encode_one(ctx, idx)
+            if self.spec is None:
+                encs, per_history = [None] * len(rows), []
+            else:
+                encs, per_history = encode_mod.encode_chunk(
+                    hists, [ctx.model_for(idx) for ctx, idx in rows],
+                    self.slot_cap, self.spec,
+                )
+            events = 0
+            for tok, h, e in zip(rows, hists, encs):
+                if e is not None:
+                    placed.append((tok, self.bucket_key(e), e))
+                    events += len(h)
             if sp:
-                sp.set("events",
-                       0 if e is None else len(ctx.histories[idx]))
-            if e is not None:
-                key = self.bucket_key(e)
-                acc = buckets.get(key)
-                if acc is None:
-                    acc = buckets[key] = ([], [])
-                    order.append(key)
-                acc[0].append(e)
-                acc[1].append((ctx, idx))
-                return key
-        ctx.route_oracle(idx, "oracle-fallback", "unencodable")
-        return _ROUTED_ORACLE
+                sp.set("events", events)
+                sp.set("histories", len(rows))
+        if self.spec is not None:
+            for path, k in (("columnar", len(rows) - len(per_history)),
+                            ("per_history", len(per_history))):
+                if k:
+                    obs.count("jepsen_engine_encoded_histories_total", k,
+                              path=path)
+        for (ctx, idx), e in zip(rows, encs):
+            if e is None:
+                ctx.route_oracle(idx, "oracle-fallback", "unencodable")
+        return placed
+
+    @staticmethod
+    def place(item, buckets, order):
+        """Append one :meth:`encode_chunk` result to its bucket; returns
+        the bucket key."""
+        tok, key, e = item
+        acc = buckets.get(key)
+        if acc is None:
+            acc = buckets[key] = ([], [])
+            order.append(key)
+        acc[0].append(e)
+        acc[1].append(tok)
+        return key
 
     def encode_buckets(self, ctx: RunContext):
         """Encode every history of ``ctx`` into raw (unstacked) shape
         buckets: ``(buckets, order)`` with ``buckets[key] = (encs,
-        tokens)``.  Unencodable histories route to the oracle
-        immediately.  This is the service path: raw buckets from many
-        contexts merge by key before a single stack+plan, so
+        tokens)``.  Unencodable histories route to the oracle as their
+        chunk is encoded.  This is the service path: raw buckets from
+        many contexts merge by key before a single stack+plan, so
         same-shape requests share compiled executables AND dispatch
         rows."""
         return self.encode_rows(ctx, range(len(ctx.histories)))
@@ -390,8 +415,17 @@ class Planner:
         Settled (WAL-replayed) rows skip as everywhere else."""
         buckets: Dict[Any, Tuple[list, list]] = {}
         order: List[Any] = []
+        pending: list = []
+        events = 0
         for idx in idxs:
-            self._accumulate(ctx, idx, buckets, order)
+            pending.append((ctx, idx))
+            events += len(ctx.histories[idx])
+            if self.chunk_full(len(pending), events):
+                for item in self.encode_chunk(pending):
+                    self.place(item, buckets, order)
+                pending, events = [], 0
+        for item in self.encode_chunk(pending):
+            self.place(item, buckets, order)
         return buckets, order
 
     # -- planning ---------------------------------------------------------
@@ -453,8 +487,10 @@ class Planner:
 
 class BucketStream:
     """One in-progress streaming pass over a :class:`Planner`:
-    :meth:`feed` accumulates (and mid-stream-flushes) one history at a
-    time, :meth:`finish` plans the residual buckets and yields them
+    :meth:`feed` takes one history at a time and encodes them a chunk
+    at a time (:meth:`Planner.encode_chunk`), flushing a bucket
+    mid-stream when it fills; :meth:`finish` encodes the last chunk,
+    plans the residual buckets and yields them
     **largest estimated cost first** — big buckets keep the dispatch
     window occupied while small ones fill the tail (the per-run half
     of the daemon's largest-cost-first scheduling; verdicts are
@@ -462,39 +498,52 @@ class BucketStream:
     a throughput decision, and ties keep first-seen order so the
     sequence stays deterministic)."""
 
-    __slots__ = ("planner", "buckets", "order", "finished")
+    __slots__ = ("planner", "buckets", "order", "finished", "pending",
+                 "pending_events")
 
     def __init__(self, planner: Planner):
         self.planner = planner
         self.buckets: Dict[Any, Tuple[list, list]] = {}
         self.order: List[Any] = []  # first-seen bucket order
         self.finished = False
+        self.pending: list = []  # rows of the chunk not yet encoded
+        self.pending_events = 0
 
     def feed(self, ctx: RunContext, idx: int):
-        """Encode history ``idx`` of ``ctx``; yields a
-        :class:`PlannedBucket` when its bucket fills mid-stream (so
-        the consumer's device work overlaps the remaining encode).
-        Unencodable histories route to the oracle pool immediately,
-        before any yield."""
+        """Take history ``idx`` of ``ctx`` into the pending chunk; when
+        the chunk is full, encode it and yield a
+        :class:`PlannedBucket` for each bucket that fills (so the
+        consumer's device work overlaps the remaining encode).  The
+        chunk's unencodable histories route to the oracle pool as its
+        encode ends, before any yield."""
         if self.finished:
             raise RuntimeError("BucketStream already finished")
+        self.pending.append((ctx, idx))
+        self.pending_events += len(ctx.histories[idx])
+        if self.planner.chunk_full(len(self.pending), self.pending_events):
+            yield from self._encode_pending()
+
+    def _encode_pending(self):
         p = self.planner
-        key = p._accumulate(ctx, idx, self.buckets, self.order)
-        if key is _ROUTED_ORACLE:
-            return  # the oracle search is already running
-        # a full bucket flushes into the dispatch window while later
-        # histories are still encoding
-        acc = self.buckets[key]
-        if p.bucketed and len(acc[0]) >= p.flush_rows:
-            pb = p.plan_rows(key, *acc)
-            self.buckets[key] = ([], [])
-            if pb is not None:
-                yield pb
+        rows, self.pending, self.pending_events = self.pending, [], 0
+        for item in p.encode_chunk(rows):
+            key = p.place(item, self.buckets, self.order)
+            # a full bucket flushes into the dispatch window while
+            # later histories are still encoding
+            acc = self.buckets[key]
+            if p.bucketed and len(acc[0]) >= p.flush_rows:
+                pb = p.plan_rows(key, *acc)
+                self.buckets[key] = ([], [])
+                if pb is not None:
+                    yield pb
 
     def finish(self):
-        """Plan every residual bucket, then yield biggest-cost-first."""
+        """Encode the last chunk (its full buckets yield as in
+        :meth:`feed`), plan every residual bucket, then yield
+        biggest-cost-first."""
         if self.finished:
             raise RuntimeError("BucketStream already finished")
+        yield from self._encode_pending()
         p = self.planner
         planned = []
         for key in self.order:

@@ -20,9 +20,13 @@ linearization order itself and admits no fixed-width encoding.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
+from itertools import repeat
+from operator import is_, itemgetter
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from .. import models as m
 
@@ -151,6 +155,25 @@ def unordered_queue_step(state, f, a, b):  # jt: traced jaxpr(dot_generals<=0, d
 
 
 @dataclass(frozen=True)
+class ColumnarOps:
+    """The columnar twin of a spec's ``encode_op``, run over a whole
+    chunk of ops at once (:func:`jepsen_tpu.ops.encode.encode_chunk`).
+
+    ``fs`` names the op functions it reads (at most 14), each coded by
+    its position (-1 for any other f); the spec's ``pure_fs`` are among
+    them.
+    ``encode(f, values, none)`` maps the ops' f codes, propagated
+    values (an object array) and which of those are None to ``(kernel
+    f codes, a-values, b-values, undecided)``: object arrays of what
+    ``encode_op`` would intern, a then b (None for V_UNKNOWN), and the
+    ops it leaves to ``encode_op``, whose histories take the
+    per-history path (the ops ``encode_op`` refuses among them)."""
+
+    fs: Tuple[str, ...]
+    encode: Callable
+
+
+@dataclass(frozen=True)
 class ModelSpec:
     """Host-side description of how a model maps onto the kernel."""
 
@@ -168,6 +191,9 @@ class ModelSpec:
     #: functions can't express); outside the dense envelope such
     #: batches go straight to the oracle, never the frontier kernel
     dense_only: bool = False
+    #: the columnar twin of ``encode_op``; None keeps the spec on the
+    #: per-history encoder
+    encode_columns: Optional[ColumnarOps] = None
 
 
 def _value_id(value, valmap: Dict[Any, int]) -> int:
@@ -197,6 +223,52 @@ def _encode_cas_op(op, valmap) -> Tuple[int, int, int]:
         old, new = op.value
         return F_CAS, _value_id(old, valmap), _value_id(new, valmap)
     return _encode_register_op(op, valmap)
+
+
+def is_none(values: list) -> np.ndarray:
+    """Which items of a list are None (by identity: a value's own
+    ``==`` may not answer a bool)."""
+    return np.frombuffer(bytes(map(is_, values, repeat(None))), bool)
+
+
+def _register_columns(f, values, none, cas: bool):
+    """Columnar twin of :func:`_encode_register_op` (``cas`` False) and
+    :func:`_encode_cas_op` (True); ``f`` codes index
+    ``REGISTER_COLUMNS.fs``, which equal F_READ, F_WRITE and F_CAS."""
+    n = len(f)
+    fk = f.astype(np.int8)
+    fk[(f == F_READ) & none] = F_READ_ANY
+    a = values.copy()
+    b = np.full((n,), None, object)
+    is_cas = f == F_CAS
+    # encode_op refuses an unknown f, a plain register's cas and a nil
+    # cas (never linearizable)
+    undecided = (f < 0) | (is_cas & (none | (not cas)))
+    if not cas:
+        return fk, a, b, undecided
+    ci = np.flatnonzero(is_cas & ~none)
+    pairs = values[ci].tolist()
+    # ``old, new = op.value`` unpacks any 2-iterable; read lists and
+    # tuples of two here and leave the rest to encode_op
+    if not (set(map(type, pairs)) <= {list, tuple}
+            and set(map(len, pairs)) <= {2}):
+        shaped = np.fromiter(
+            (type(v) in (list, tuple) and len(v) == 2 for v in pairs),
+            bool, len(pairs),
+        )
+        undecided[ci[~shaped]] = True
+        ci = ci[shaped]
+        pairs = [v for v, ok in zip(pairs, shaped.tolist()) if ok]
+    # fromiter: never a nested array, whatever the values hold
+    a[ci] = np.fromiter(map(itemgetter(0), pairs), object, len(pairs))
+    b[ci] = np.fromiter(map(itemgetter(1), pairs), object, len(pairs))
+    return fk, a, b, undecided
+
+
+REGISTER_COLUMNS = ColumnarOps(fs=("read", "write", "cas"),
+                               encode=partial(_register_columns, cas=False))
+CAS_REGISTER_COLUMNS = ColumnarOps(fs=("read", "write", "cas"),
+                                   encode=partial(_register_columns, cas=True))
 
 
 def _encode_mutex_op(op, valmap) -> Tuple[int, int, int]:
@@ -436,6 +508,7 @@ SPECS: Dict[type, ModelSpec] = {
         encode_op=_encode_register_op,
         init_state=_register_init,
         pure_fs=("read",),
+        encode_columns=REGISTER_COLUMNS,
     ),
     m.CASRegister: ModelSpec(
         name="cas-register",
@@ -443,6 +516,7 @@ SPECS: Dict[type, ModelSpec] = {
         encode_op=_encode_cas_op,
         init_state=_register_init,
         pure_fs=("read",),
+        encode_columns=CAS_REGISTER_COLUMNS,
     ),
     m.Mutex: ModelSpec(
         name="mutex",

@@ -359,9 +359,11 @@ def test_pipeline_metrics_and_span():
     by_name = {}
     for s in spans:
         by_name.setdefault(s.name, []).append(s)
-    # one encode per history, carrying the history's event count
+    # one encode per chunk (here the whole batch), carrying its
+    # histories' event count
     encs = by_name["engine/encode"]
-    assert len(encs) == len(hists)
+    assert len(encs) == 1
+    assert sum(int(s.attrs["histories"]) for s in encs) == len(hists)
     assert sum(int(s.attrs["events"]) for s in encs) == sum(
         len(x) for x in hists)
     # one plan per flush, one issue per dispatch, a settle per retire
@@ -677,3 +679,78 @@ def test_planner_stream_equals_feed_finish_composition():
     assert via_stream == via_feed
     assert planner_a.n_buckets == planner_b.n_buckets
     assert planner_a.n_flushes == planner_b.n_flushes
+
+
+@pytest.mark.parametrize("model_name", ["cas-register", "mutex"])
+def test_chunked_bucket_stream_matches_per_history_encode(monkeypatch,
+                                                          model_name):
+    """BucketStream encodes a chunk at a time: bucket keys, row tokens
+    (in order) and oracle-routed rows equal one-history-at-a-time
+    encode, settled rows are skipped, the engine/encode spans carry
+    the encoded histories' events, and the path counter counts both
+    the columnar pass and the per-history one.  A spec with no columnar
+    encoder encodes each history as it is fed."""
+    monkeypatch.setattr(encode, "CHUNK_EVENTS", 50)  # several chunks
+    # chunks this small would not pay for the columnar pass: take it
+    monkeypatch.setattr(encode, "_PASS_COST_EVENTS", 0)
+    if model_name == "cas-register":
+        model = m.cas_register(0)
+        hists = mixed_corpus(seed=28)
+        # a process invoking twice: the chunk's per-history path
+        hists.insert(3, History([invoke_op(0, "write", 1),
+                                 invoke_op(0, "write", 2),
+                                 ok_op(0, "write", 2)]).index_ops())
+    else:
+        model = m.mutex()
+        hists = [History([invoke_op(p, "acquire"), ok_op(p, "acquire"),
+                          invoke_op(p, "release"), ok_op(p, "release")]
+                         * k).index_ops()
+                 for p, k in [(0, 1), (1, 3), (2, 20), (0, 2)]]
+        hists.append(wide_history())
+    settled = {1, len(hists) - 3}
+    ctx = planning.RunContext(model, hists, oracle_fallback=False)
+    for i in settled:
+        ctx.assign(i, {"valid?": True, "engine": "replayed"})
+    planner = planning.Planner(model, spec=ctx.spec, slot_cap=32,
+                               frontier=64)
+    obs.enable(reset=True)
+    stream = planner.open_stream()
+    got = []
+    for idx in range(len(hists)):
+        got.extend(stream.feed(ctx, idx))
+    got.extend(stream.finish())
+    spans = [s for s in obs.tracer().finished(cat="engine")
+             if s.name == "engine/encode"]
+    reg = obs.registry()
+    columnar = reg.value("jepsen_engine_encoded_histories_total",
+                         path="columnar") or 0
+    per_history = reg.value("jepsen_engine_encoded_histories_total",
+                            path="per_history") or 0
+    obs.enable(reset=True)
+
+    # one history at a time, as before chunking
+    want_rows, want_routed, events = {}, [], 0
+    for i, hist in enumerate(hists):
+        if i in settled:
+            continue
+        e = encode.encode_history(hist, model, 32, ctx.spec)
+        if e is None:
+            want_routed.append(i)
+            continue
+        events += len(hist)
+        want_rows.setdefault(encode.bucket_key(e, 32), []).append(i)
+    assert {pb.key: [idx for _, idx in pb.rows] for pb in got} == want_rows
+    assert all(c is ctx for pb in got for c, _ in pb.rows)
+    assert [i for i, r in enumerate(ctx.results)
+            if r and r["engine"] == "unencodable"] == want_routed
+    assert want_routed  # the wide history
+    assert len(spans) > 1
+    assert sum(int(s.attrs["events"]) for s in spans) == events
+    assert sum(int(s.attrs["histories"]) for s in spans) == (
+        len(hists) - len(settled))
+    assert columnar + per_history == len(hists) - len(settled)
+    if model_name == "cas-register":
+        assert per_history == 1 and columnar > 1
+    else:
+        assert columnar == 0
+        assert len(spans) == len(hists) - len(settled)  # one a history
