@@ -2,7 +2,7 @@
 # the host (not available in the build image — run them on a docker-
 # capable machine).
 
-.PHONY: test bench bench-gate check lint lint-fixtures lint-jaxpr-fixtures trace-smoke pipeline-smoke serve-smoke chaos-smoke online-smoke fleet-smoke mesh-smoke decompose-smoke tune-smoke elle-smoke kernels-smoke obs-fleet-smoke drift-smoke docker-smoke docker-up docker-down
+.PHONY: test check lint lint-fixtures lint-jaxpr-fixtures trace-smoke pipeline-smoke serve-smoke chaos-smoke online-smoke fleet-smoke mesh-smoke decompose-smoke tune-smoke elle-smoke kernels-smoke obs-fleet-smoke drift-smoke docker-smoke docker-up docker-down
 
 test:
 	python -m pytest tests/ -q
@@ -10,9 +10,9 @@ test:
 # the full local gate: static analysis + unit tests + the
 # observability, pipeline, checker-service, slice-dispatch,
 # decomposition, auto-tune, transactional-screen, closure/union
-# kernel, and drift-sentinel smoke checks, plus the bench regression
-# gate over the recorded window history
-check: lint test trace-smoke pipeline-smoke serve-smoke chaos-smoke online-smoke fleet-smoke mesh-smoke decompose-smoke tune-smoke elle-smoke kernels-smoke obs-fleet-smoke drift-smoke bench-gate
+# kernel, and drift-sentinel smoke checks.  Performance is measured
+# on the chip by perf/run.py (BENCHMARK.json, PERF.md), not here
+check: lint test trace-smoke pipeline-smoke serve-smoke chaos-smoke online-smoke fleet-smoke mesh-smoke decompose-smoke tune-smoke elle-smoke kernels-smoke obs-fleet-smoke drift-smoke
 
 # jtlint static analysis (doc/static-analysis.md): all eight passes —
 # trace-safety, lock-discipline, concurrency (whole-program race
@@ -176,19 +176,6 @@ obs-fleet-smoke:
 # round-trip producing a loadable capture manifest
 drift-smoke:
 	env JAX_PLATFORMS=cpu python -m jepsen_tpu.obs.drift_smoke
-
-bench:
-	python bench.py
-
-# bench regression gate (doc/observability.md "Bench regression
-# gates"): one fresh reduced-L window vs the best recorded same-label,
-# same-device-kind window in BENCH_tpu_windows.jsonl — exits nonzero
-# when any vs_baseline metric lands below best × 0.85.  On a CPU-only
-# CI host with no recorded cpu window this passes vacuously (gate runs
-# never append to the history), and on the TPU campaign host it stops
-# kernel PRs from silently losing recorded throughput.
-bench-gate:
-	env JAX_PLATFORMS=cpu JEPSEN_TPU_BENCH_L=200 python bench.py --gate
 
 # BASELINE config 2: etcd register + partition nemesis over real SSH in
 # the dockerized 5-node cluster; artifacts land in docker/smoke-store/.

@@ -295,12 +295,11 @@ def _classify_scc(g: Graph, scc: List[Any], screen, anomalies) -> None:
 _SCREEN_CHOICE: dict = {}
 
 #: never even calibrate the O(n³) closure kernel past this many
-#: vertices: on the CPU backend it loses to SCC well before (0.6× at
-#: n=256, benchmarks/elle_bench.py) — on the real chip it still wins
-#: there (1.6× at n=256, benchmarks/elle_results_tpu.json, 2026-07-31),
-#: which is why the cap sits at 512
-#: and not lower — and a first-touch calibration on a huge padded
-#: matrix would burn minutes proving the obvious.  classify_graphs
+#: vertices: on the CPU backend it loses to SCC well before n=256,
+#: while the chip's matrix unit is expected to keep it ahead there
+#: (not measured on the chip on today's code), which is why the cap
+#: sits at 512 and not lower — and a first-touch calibration on a huge
+#: padded matrix would burn minutes proving the obvious.  classify_graphs
 #: applies it per SCC of a graph's union, not to the whole graph
 DEVICE_SCREEN_MAX_VERTICES = 512
 
@@ -581,8 +580,7 @@ def _classify_screened(graphs: List[Graph], executor=None,
     if count:
         _count_routes(parts_of, screens, route)
         # a graph whose union has a cycle still pays the witness
-        # search — the measured "witness-search fallback" fraction of
-        # the bench headline
+        # search — the "witness-search fallback" fraction
         n_fallback = sum(1 for p in parts_of if p)
         if n_fallback:
             obs.count("jepsen_elle_witness_fallback_total", n_fallback)

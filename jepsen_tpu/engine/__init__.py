@@ -17,7 +17,7 @@ stages the serial path used to run back-to-back:
    ``checker.linear`` searches on a worker pool *concurrently* with
    device work instead of after it.
 
-Verdicts are independent of the window size and bucketing — window=1
+Verdicts are independent of the window size — window=1
 is exactly the historical serial dispatch-sync-dispatch path (pinned
 by ``tests/test_engine.py`` and ``make pipeline-smoke``).  Pipeline
 bubble time, in-flight depth, and bucket counts report through the
@@ -55,7 +55,6 @@ from .planning import (  # noqa: F401
     Planner,
     PlannedBucket,
     RunContext,
-    default_bucketed,
     estimated_cost,
     merge_buckets,
 )

@@ -232,8 +232,7 @@ def merge_partition_results(parts: Sequence[Tuple[Any, dict]]) -> dict:
     uniform sub-engine (or ``"mixed"``) so engine-rate stats stay
     honest; whenever ANY sub-history routed to the oracle the count
     rides along as ``oracle-partitions`` — a ``"mixed"`` engine must
-    not hide oracle load from routing accounting (bench --decompose,
-    decompose-smoke)."""
+    not hide oracle load from routing accounting (decompose-smoke)."""
     n = len(parts)
     n_oracle = sum(
         1 for _k, r in parts

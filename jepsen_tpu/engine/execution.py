@@ -139,8 +139,7 @@ class DispatchWindow:
     before the next one is issued, reproducing the historical
     dispatch-sync-dispatch path exactly.  The window is shared
     machinery — ``check_batch`` dispatches bucket chunks through it,
-    ``ops.cycles`` its Elle screen buckets, and ``bench.py`` its
-    pipelined measurement, so the benchmark times the code users run.
+    and ``ops.cycles`` its Elle screen buckets.
 
     A window is **owner-thread confined** (``# jt: guarded-by
     (owner-thread)`` on its state, checked by the lock-discipline lint

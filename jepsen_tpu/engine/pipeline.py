@@ -2,10 +2,9 @@
 
 The serial path ran encode → H2D → kernel → D2H → oracle-fallback
 strictly in sequence, so the device idled during host work and the
-exponential CPU searches started only after the last dispatch returned
-(bench.py measured ~18% headroom from exactly this bubble).  This
-module keeps the device saturated the way serving stacks keep their
-accelerators fed (continuous batching / input pipelines):
+exponential CPU searches started only after the last dispatch returned.
+This module keeps the device saturated the way serving stacks keep
+their accelerators fed (continuous batching / input pipelines):
 
 - **Shape buckets.**  Histories encode one at a time and accumulate in
   per-``(E, C)`` buckets (``encode.bucket_key``), so a 30-op history in
@@ -95,7 +94,6 @@ from .planning import (  # noqa: F401 — back-compat re-exports
     DEFAULT_FLUSH_ROWS,
     Planner,
     RunContext,
-    default_bucketed,
     estimated_cost,
     finish_run_telemetry,
 )
@@ -115,7 +113,6 @@ def run(
     max_dispatch: Optional[int] = None,
     oracle_budget_s: Optional[float] = None,
     window: Optional[int] = None,
-    bucketed: Optional[bool] = None,
     decomposed: Optional[bool] = None,
     wal_sink=None,
     wal_replay=None,
@@ -180,7 +177,7 @@ def run(
                 planner = Planner(
                     ctx.model, spec=ctx.spec, slot_cap=slot_cap,
                     frontier=frontier, max_closure=max_closure,
-                    max_dispatch=max_dispatch, bucketed=bucketed,
+                    max_dispatch=max_dispatch,
                     n_devices=n_devices,
                 )
                 st = planners[id(ctx)] = (planner, planner.open_stream())

@@ -32,7 +32,6 @@ coalescing sound).
 
 from __future__ import annotations
 
-import os
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from .. import obs
@@ -65,14 +64,6 @@ def _note_settle_times(result: dict) -> None:
     if (result.get("valid?") is False
             and reg.value("jepsen_run_first_violation_seconds") is None):
         obs.gauge_set("jepsen_run_first_violation_seconds", round(dt, 6))
-
-
-def default_bucketed() -> bool:
-    """Shape bucketing default: on unless ``JEPSEN_TPU_ENGINE_BUCKETED``
-    is falsy."""
-    return os.environ.get("JEPSEN_TPU_ENGINE_BUCKETED", "1").lower() not in (
-        "0", "false", "off", "no",
-    )
 
 
 def flush_rows_default() -> int:
@@ -278,7 +269,6 @@ class Planner:
         spec=_UNSET,
         max_closure: Optional[int] = None,
         max_dispatch: Optional[int] = None,
-        bucketed: Optional[bool] = None,
         flush_rows: Optional[int] = None,
         n_devices: int = 1,
     ):
@@ -292,9 +282,6 @@ class Planner:
         self.max_closure = max_closure
         self.max_dispatch = (
             wgl.DEFAULT_MAX_DISPATCH if max_dispatch is None else max_dispatch
-        )
-        self.bucketed = (
-            default_bucketed() if bucketed is None else bool(bucketed)
         )
         # the flush threshold is a PER-DEVICE feed rate: on an n-device
         # mesh a flush fans its rows out across all n chips, so
@@ -323,22 +310,14 @@ class Planner:
                 or events >= encode_mod.CHUNK_EVENTS
                 or rows >= self.flush_rows)
 
-    def bucket_key(self, e) -> Optional[tuple]:
-        from ..ops import encode as encode_mod
-
-        return (
-            encode_mod.bucket_key(e, self.slot_cap) if self.bucketed else None
-        )
-
     def encode_chunk(self, rows: list) -> list:
         """Encode a chunk of ``(ctx, idx)`` rows at once (the ONE
         shared encode/route step — oracle routing and bucket keying
         cannot diverge between the in-process stream and the service's
         encode_buckets): ``[(token, key, encoded)]`` for the rows that
-        encoded, in row order; ``None`` IS a valid key in unbucketed
-        mode.  An unencodable row routes to the oracle as the chunk's
-        encode ends — that search starts NOW, on the worker pool,
-        overlapping all remaining encode and device work.  The
+        encoded, in row order.  An unencodable row routes to the oracle
+        as the chunk's encode ends — that search starts NOW, on the
+        worker pool, overlapping all remaining encode and device work.  The
         per-history model (``ctx.model_for``) seeds the init state —
         decomposed sub-histories share this planner's spec family but
         each carry their own partition's seeded sub-model.
@@ -366,7 +345,8 @@ class Planner:
             events = 0
             for tok, h, e in zip(rows, hists, encs):
                 if e is not None:
-                    placed.append((tok, self.bucket_key(e), e))
+                    placed.append(
+                        (tok, encode_mod.bucket_key(e, self.slot_cap), e))
                     events += len(h)
             if sp:
                 sp.set("events", events)
@@ -440,12 +420,7 @@ class Planner:
         if not encs:
             return None
         with obs.span("engine/plan", cat="engine") as sp:
-            if key is not None:
-                E, C = key
-            else:
-                # unbucketed (historical) stacking: one global padded
-                # shape
-                E, C = encode_mod.global_shape(encs, self.slot_cap)
+            E, C = key
             batch = encode_mod.stack_encoded(encs, rows, E, C)
             arrays = (
                 batch.init_state, batch.ev_slot, batch.cand_slot,
@@ -531,7 +506,7 @@ class BucketStream:
             # a full bucket flushes into the dispatch window while
             # later histories are still encoding
             acc = self.buckets[key]
-            if p.bucketed and len(acc[0]) >= p.flush_rows:
+            if len(acc[0]) >= p.flush_rows:
                 pb = p.plan_rows(key, *acc)
                 self.buckets[key] = ([], [])
                 if pb is not None:

@@ -9,8 +9,7 @@ serial-equivalent baseline) and 4, on both kernel routes (dense
 automaton, and the generic frontier kernel via an explicit closure
 cap).  Fails loudly on:
 
-- verdict divergence between window sizes, between bucketed and the
-  historical single-batch encode, or against the CPU oracle;
+- verdict divergence between window sizes, or against the CPU oracle;
 - missing pipeline telemetry: ``jepsen_engine_inflight_depth`` must
   exceed 1 on the window-4 run (proof the overlap actually happened —
   the acceptance gate on hosts without a chip), equal 1 on the
@@ -102,24 +101,21 @@ def main(argv=None) -> int:
     }
     for name, kw in configs.items():
         baseline = None
-        for window, bucketed in ((1, False), (1, True), (4, True)):
+        for window in (1, 4):
             obs.enable(reset=True)
-            outs = wgl.check_batch(
-                model, hists, window=window, bucketed=bucketed, **kw
-            )
+            outs = wgl.check_batch(model, hists, window=window, **kw)
             verdicts = [o["valid?"] for o in outs]
             check(
                 verdicts == oracle,
-                f"{name} w={window} bucketed={bucketed}: verdicts "
-                f"{verdicts} != oracle {oracle}",
+                f"{name} w={window}: verdicts {verdicts} != oracle {oracle}",
             )
             if baseline is None:
                 baseline = outs
             else:
                 check(
                     verdicts == [o["valid?"] for o in baseline],
-                    f"{name} w={window} bucketed={bucketed} diverged "
-                    "from the serial baseline",
+                    f"{name} w={window} diverged from the serial "
+                    "baseline",
                 )
             check(
                 outs[-1].get("engine") == "oracle-fallback",
@@ -142,12 +138,11 @@ def main(argv=None) -> int:
                     f"{name} window=4: no overlap recorded "
                     f"(inflight depth {depth})",
                 )
-            if bucketed:
-                check(
-                    (reg.value("jepsen_engine_bucket_count") or 0) >= 2,
-                    f"{name}: mixed-shape corpus produced "
-                    f"{reg.value('jepsen_engine_bucket_count')} buckets",
-                )
+            check(
+                (reg.value("jepsen_engine_bucket_count") or 0) >= 2,
+                f"{name}: mixed-shape corpus produced "
+                f"{reg.value('jepsen_engine_bucket_count')} buckets",
+            )
             check(
                 _bubble_count(reg) > 0,
                 f"{name} w={window}: no bubble-time observations",

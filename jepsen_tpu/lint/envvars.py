@@ -82,9 +82,6 @@ REGISTRY: Tuple[EnvVar, ...] = (
            "elle/cycles.py",
            "Elle cycle-screen routing: `auto`/`1` (device screens) or "
            "`0` (pure CPU classify)"),
-    EnvVar("JEPSEN_TPU_ENGINE_BUCKETED", "1",
-           "engine/planning.py",
-           "shape bucketing; `0` pads every history to one (E, C)"),
     EnvVar("JEPSEN_TPU_ENGINE_DECOMPOSE", "1",
            "engine/decompose.py",
            "key-partition decomposition front-end; `0` disables"),

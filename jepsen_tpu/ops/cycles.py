@@ -541,7 +541,7 @@ def _settle_closure_obs(plan, rounds: np.ndarray, n_live: int) -> None:
     # estimated MXU work this dispatch actually ran: each round squares
     # every live row's packed plane stack (~2·E³ flops per E-plane;
     # the lifted 2E-planes ride the plan's frontier weight), so the
-    # bench can report a closure FLOP-rate without re-deriving shapes
+    # closure FLOP-rate can be read without re-deriving shapes
     obs.count("jepsen_cycles_closure_flops_total",
               int(2.0 * float(plan.E) ** 3 * plan.frontier * used
                   * max(1, n_live)),

@@ -39,8 +39,7 @@ device mesh like the generic kernel.
 Cost per event is a handful of fused vector ops on [C, V, 2^C/32]
 uint32 tensors — for the practical C ≤ 12, V ≤ 32 envelope a few KB per
 history — versus the generic kernel's two O((F + F·C) log) sorts per
-closure pass.  Measured on one TPU chip this is orders of magnitude
-faster (see bench.py).
+closure pass.
 """
 
 from __future__ import annotations
@@ -324,16 +323,13 @@ def _or_select(x, wb: int):
 #: permutations as ONE one-hot batched uint32 matmul over the packed
 #: axis — _subset_perms — so the union/drop maps ride the same
 #: matrix-unit path the closure kernels do).  Same results
-#: bit-for-bit (differentially tested).  The on-chip A/B that settled
-#: the default
-#: (2026-07-31 window, B=16384 L=1000 flagship): unroll 21,299 h/s vs
-#: gather 13,451 h/s — the gather lowering dominated the closure cost
-#: exactly as the roofline model predicted (BENCH_tpu_windows.jsonl
-#: rows 18:15/18:17Z; not re-measured on today's code).
+#: bit-for-bit (differentially tested).  The default is "unroll"
+#: because the gather lowering's index gathers dominate the closure
+#: cost, as the roofline model predicts; the gap has not been measured
+#: on the chip on today's code.
 #: the default subset-union lowering — the ONE definition every
-#: consumer (kernel build, bench diag reporting, headline-artifact
-#: gating) reads, so a future default flip can't silently mislabel
-#: bench windows or misroute the headline artifact
+#: consumer (kernel build, the tuner, the benchmark's knob report)
+#: reads, so a future default flip can't silently mislabel a run
 DEFAULT_UNION = "unroll"
 
 

@@ -692,8 +692,8 @@ def bucket_key(
     into: events round to ``event_bucket`` (bounding recompiles),
     candidate lanes to the history's own peak concurrency rounded to 4
     and capped at ``slot_cap``.  Shared by :func:`batch_encode`'s
-    bucketed mode and the streaming bucketer in
-    :mod:`jepsen_tpu.engine.pipeline`, so the two can never disagree
+    bucketed mode and the engine's streaming bucketer
+    (:mod:`jepsen_tpu.engine.planning`), so the two can never disagree
     about which histories share a compiled shape."""
     E = round_up(e.ev_slot.shape[0], event_bucket)
     C = min(slot_cap, round_up(e.max_open, 4))
@@ -703,14 +703,12 @@ def bucket_key(
 def global_shape(
     encoded: Sequence[EncodedHistory], slot_cap: int, event_bucket: int = 64
 ) -> tuple:
-    """The historical single-batch padded ``(E, C)``: every history
-    padded to the global max event count, candidate lanes to the
-    batch's peak concurrency (rounded to 4, capped at ``slot_cap``) —
-    this shrinks the frontier-expansion width and sort size, usually
-    the dominant cost.  The ONE definition both ``batch_encode``'s
-    unbucketed mode and the engine's ``bucketed=False`` path read, so
-    "bucketed=False restores the old single-batch behavior" can never
-    silently desynchronize."""
+    """The single-batch padded ``(E, C)`` of :func:`batch_encode`'s
+    unbucketed mode: every history padded to the global max event
+    count, candidate lanes to the batch's peak concurrency (rounded to
+    4, capped at ``slot_cap``) — this shrinks the frontier-expansion
+    width and sort size, usually the dominant cost.  The engine never
+    uses it: it always stacks per :func:`bucket_key`."""
     E = round_up(max(e.ev_slot.shape[0] for e in encoded), event_bucket)
     C = min(slot_cap, round_up(max(e.max_open for e in encoded), 4))
     return E, C
@@ -778,12 +776,13 @@ def batch_encode(
     """Encode histories into padded batches; unencodable ones land in
     ``fallback`` for the CPU oracle.
 
-    ``bucketed=False`` (the default, the historical behavior) returns
-    ONE :class:`EncodedBatch` padded to the global max event count —
-    every short history pays the longest history's padding.
+    ``bucketed=False`` (the default) returns ONE :class:`EncodedBatch`
+    padded to the global max event count (:func:`global_shape`) —
+    every short history pays the longest history's padding; the
+    kernel-level callers that drive one batch by hand use this form.
     ``bucketed=True`` instead returns a ``List[EncodedBatch]``, one per
     padded ``(E, C)`` shape bucket (:func:`bucket_key`), sorted by
-    shape, so the engine dispatches tight shapes; the global
+    shape — the shapes the engine dispatches; the global
     ``fallback`` list rides on the FIRST returned batch (an
     all-fallback input returns a single zero-row batch carrying it)."""
     spec = spec_for(model)

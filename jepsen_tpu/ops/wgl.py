@@ -568,10 +568,10 @@ LINEAR_FRONTIER_SPECS = frozenset(
 #: specs the CPU direct checker beats EVERY device kernel on, even
 #: inside the dense envelope: the unordered queue factors per value
 #: into a greedy bipartite matching (checker/locks_direct.py,
-#: _queue_check_events) measured at 34.8k h/s single-core on the
-#: queue-bench corpus vs the dense bitset kernel's 7.5k at B=1024 —
-#: 4.6x — and 204x the generic search.  Routing it off the device
-#: entirely is the measured choice.
+#: _queue_check_events), near-linear in the history where the dense
+#: bitset kernel carries 2^C subsets an event — so it is routed off the
+#: device entirely (a choice not re-measured on the chip on today's
+#: code).
 DIRECT_FIRST_SPECS = frozenset({"unordered-queue"})
 
 
@@ -1124,7 +1124,6 @@ def check_batch(
     max_dispatch: int = DEFAULT_MAX_DISPATCH,
     oracle_budget_s: Optional[float] = None,
     window: Optional[int] = None,
-    bucketed: Optional[bool] = None,
     decomposed: Optional[bool] = None,
 ) -> List[dict]:
     """Check a batch of histories on the accelerator; per-history result
@@ -1157,9 +1156,7 @@ def check_batch(
     in-flight ``window`` (default 4, ``JEPSEN_TPU_ENGINE_WINDOW``; 1 =
     strictly serial, dispatch-sync-dispatch), and CPU-oracle fallbacks
     run on a worker pool concurrently with device work.  Verdicts are
-    independent of ``window`` and ``bucketed`` — those knobs only move
-    wall time (``bucketed=False`` restores the historical one-padded-
-    batch encode).
+    independent of ``window``, which only moves wall time.
 
     Partitionable models (multi-register per key, multi-mutex per lock
     name, unordered queue per value — the partition protocol on
@@ -1186,7 +1183,6 @@ def check_batch(
         max_dispatch=max_dispatch,
         oracle_budget_s=oracle_budget_s,
         window=window,
-        bucketed=bucketed,
         decomposed=decomposed,
     )
 

@@ -59,8 +59,8 @@ def configure_compile_cache() -> str:
     When ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and
     nothing is set here.  Otherwise the cache goes to
     ``<checkout>/.jax_cache``, the same path on every call and every
-    run.  The CLI, ``serve``, ``bench.py`` and ``chip_smoke.py`` call
-    this once at start-up."""
+    run.  The CLI, ``serve`` and ``chip_smoke.py`` call this once at
+    start-up."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -3,8 +3,8 @@ and the oracle worker pool stay warm in one long-lived daemon; client
 runs ship encoded histories over a local HTTP seam and share
 coalesced device dispatches.
 
-Why: every ``cli test`` run pays backend init and per-shape re-jit —
-the bench's r01–r05 rows show init alone can eat the accelerator win.
+Why: every ``cli test`` run pays backend init and per-shape re-jit,
+and init alone can eat the accelerator win.
 For the ROADMAP's millions-of-users traffic the device must be
 resident; the paper's ``check(self, test, history, opts)`` seam was
 designed exactly so the execution substrate could swap without

@@ -1,8 +1,8 @@
 """``python -m jepsen_tpu.serve`` — run the resident checker daemon.
 
 Equivalent to ``jepsen-tpu serve --checker``; exists so the client's
-auto-start (``JEPSEN_TPU_SERVICE=auto``, ``bench.py
---against-service``) has a suite-independent entry point.
+auto-start (``JEPSEN_TPU_SERVICE=auto``) has a suite-independent
+entry point.
 """
 
 from __future__ import annotations

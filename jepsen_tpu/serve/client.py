@@ -632,8 +632,8 @@ def _reap(proc, grace_s: float = 10.0) -> None:
 def spawn_daemon(port: Optional[int] = None,
                  wait_s: float = 60.0) -> ServiceClient:
     """Start a daemon subprocess (``python -m jepsen_tpu.serve``) and
-    wait until it answers /healthz.  Used by ``JEPSEN_TPU_SERVICE=auto``
-    and ``bench.py --against-service``."""
+    wait until it answers /healthz.  Used by
+    ``JEPSEN_TPU_SERVICE=auto``."""
     client = ServiceClient(port=port)
     if client.port is None:
         raise ServiceUnavailable("invalid JEPSEN_TPU_SERVE_PORT")
@@ -746,7 +746,6 @@ def check_batch(model, histories, *, client: Optional[ServiceClient] = None,
     serviceable = (
         opts.get("oracle_budget_s") is None
         and opts.get("window") is None
-        and opts.get("bucketed") is not False
         # an explicit decomposed= override is engine-side only (the
         # daemon decomposes per ITS environment): honoring it means
         # running in-process, not silently dropping the opt on the wire

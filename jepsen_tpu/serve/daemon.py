@@ -3,8 +3,7 @@ compiled-kernel cache, and the oracle worker pool; many client runs
 share them.
 
 Why a daemon: every ``cli test`` run pays backend init and per-shape
-re-jit from scratch — the r01–r05 bench rows show init alone can eat
-the accelerator win.  Keeping the mesh and jit cache resident
+re-jit from scratch, and init alone can eat the accelerator win.  Keeping the mesh and jit cache resident
 amortizes both across runs and users; the ``check(...)`` seam stays
 the client API (jepsen_tpu.serve.client), so tests don't change.
 
@@ -860,8 +859,7 @@ class CheckerDaemon:
                        if st.tag == tag]
             rep = streams[0]
             planner = planning.Planner(
-                rep.model, spec=rep.spec, bucketed=True,
-                **first.plan_opts,
+                rep.model, spec=rep.spec, **first.plan_opts,
             )
             merged, order = planning.merge_buckets(
                 (st.buckets, st.order) for st in streams
@@ -1351,7 +1349,7 @@ class CheckerDaemon:
         with obs.span("serve/plan", cat="serve", histories=len(histories)):
             for tag, sctx in run.streams():
                 planner = planning.Planner(
-                    sctx.model, spec=sctx.spec, bucketed=True, **plan_opts
+                    sctx.model, spec=sctx.spec, **plan_opts
                 )
                 buckets, order = planner.encode_buckets(sctx)
                 streams.append(
@@ -1565,8 +1563,7 @@ class CheckerDaemon:
                 if not idxs:
                     continue
                 planner = planning.Planner(
-                    sctx.model, spec=sctx.spec, bucketed=True,
-                    **s.plan_opts,
+                    sctx.model, spec=sctx.spec, **s.plan_opts,
                 )
                 buckets, order = planner.encode_rows(sctx, idxs)
                 streams.append(

@@ -5,10 +5,9 @@ picks for the *attached* device (ROADMAP item 4): a coordinate-descent
 search (the schedule-fine-tuning shape of arXiv:2406.20037, sized for
 our six-knob space) from the current defaults over
 
-- ``union_mode`` — the dense subset-union lowering (the stable ~1.6×
-  unroll/gather gap in BENCH_tpu_windows.jsonl is exactly what this
-  coordinate re-measures per chip; ``matmul`` recasts the subset maps
-  as one-hot MXU matmuls),
+- ``union_mode`` — the dense subset-union lowering (this coordinate
+  re-measures the unroll/gather gap per chip; ``matmul`` recasts the
+  subset maps as one-hot MXU matmuls),
 - ``closure_mode`` — fixed-round vs convergence-early-exit boolean
   closure in the Elle cycle screens (the sync cost of the early-exit
   ``while_loop`` only pays off at large vertex buckets),
@@ -65,7 +64,7 @@ from . import artifact
 PROFILES: Dict[str, Dict[str, Any]] = {
     # corpus shape matters: the sweep optimizes wall time ON ITS OWN
     # corpora, so these must look like production traffic (hundreds of
-    # ops per history — the flagship bench runs 1000-op histories), or
+    # ops per history — the benchmark's CAS cells run 1000-op histories), or
     # a pick that wins at toy shapes loses at real ones (measured: an
     # L=40 sweep corpus picked a union mode 2× slower at L=200)
     "default": dict(
@@ -260,7 +259,7 @@ class _Runner:
         planner = planning.Planner(
             model, spec=ctx.spec, slot_cap=SLOT_CAP, frontier=FRONTIER,
             max_closure=max_closure, max_dispatch=max_dispatch,
-            bucketed=True, flush_rows=flush_rows,
+            flush_rows=flush_rows,
         )
         ex = execution.Executor(window, max_dispatch=max_dispatch)
         t0 = time.perf_counter()
@@ -434,7 +433,6 @@ def measure_cost_table(runner: _Runner, corpora, profile: Dict[str, Any],
                 planner = planning.Planner(
                     model, spec=ctx.spec, slot_cap=SLOT_CAP,
                     frontier=FRONTIER, max_closure=max_closure,
-                    bucketed=True,
                 )
                 if planner.spec is None:
                     continue

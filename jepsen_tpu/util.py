@@ -125,8 +125,8 @@ def retry(delay_seconds: float, fn: Callable[[], T], tries: Optional[int] = None
 
 def free_port() -> int:
     """An ephemeral localhost TCP port (bind to 0, read, release).
-    The one shared copy — the localkv suite, the checker-service
-    bench, and the tests all allocate scratch ports this way."""
+    The one shared copy — the localkv suite, the checker service's
+    smoke checks, and the tests all allocate scratch ports this way."""
     import socket
 
     s = socket.socket()

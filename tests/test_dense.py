@@ -397,3 +397,14 @@ def test_unknown_union_mode_rejected():
 
     with pytest.raises(ValueError):
         dense.build_dense("cas-register", 8, 4, 8, union="zip")
+
+
+def test_union_mode_resolves_env_then_default(monkeypatch):
+    from jepsen_tpu.ops import dense
+
+    # every consumer resolves the lowering through dense._union_mode:
+    # the env override wins, and without it the one DEFAULT_UNION holds
+    monkeypatch.setenv("JEPSEN_TPU_DENSE_UNION", "gather")
+    assert dense._union_mode() == "gather"
+    monkeypatch.delenv("JEPSEN_TPU_DENSE_UNION")
+    assert dense._union_mode() == dense.DEFAULT_UNION

@@ -1,12 +1,12 @@
-"""Drift-sentinel, profiling, and bench-gate tests (ISSUE 17).
+"""Drift-sentinel and profiling tests.
 
 The sentinel (jepsen_tpu/obs/drift.py) scores dispatch-journal rows
 against the cost model's prediction — these tests pin the residual
 math (deterministic EWMA, median normalization), the
 tolerate-anything row handling (old schemas and unpriceable shapes
 must become skip counters, never NaN ratios), the exactly-once
-threshold-crossing latch with its durable journal marker, the
-profiling capture round-trip, and the pure half of ``bench --gate``.
+threshold-crossing latch with its durable journal marker, and the
+profiling capture round-trip.
 """
 
 import math
@@ -266,82 +266,3 @@ def test_profile_capture_propagates_work_errors(tmp_path):
     # the manifest still landed (trace stopped, inventory sampled)
     loaded = profiling.load_manifest(out)
     assert loaded is not None and loaded["idle"] is False
-
-
-# ---------------------------------------------------------------------------
-# bench --gate (the pure verdict half)
-# ---------------------------------------------------------------------------
-
-
-def _window(vsb, platform="cpu", label=None, **extra):
-    rec = {"captured_at": "t0", "value": vsb * 10000.0,
-           "vs_baseline": vsb, "diag": {"platform": platform}}
-    if label:
-        rec["bench"] = label
-    rec.update(extra)
-    return rec
-
-
-def test_gate_passes_at_parity():
-    import bench
-
-    verdict = bench.gate_verdict(
-        {"vs_baseline": 1.0}, [_window(1.0)], "cpu", 0.85)
-    assert verdict["gate"] == "pass"
-    assert verdict["metrics"][0]["ok"] is True
-
-
-def test_gate_fails_on_a_slowed_window():
-    import bench
-
-    verdict = bench.gate_verdict(
-        {"vs_baseline": 0.5}, [_window(1.0)], "cpu", 0.85)
-    assert verdict["gate"] == "fail"
-    row = verdict["metrics"][0]
-    assert row["ok"] is False
-    assert row["floor"] == pytest.approx(0.85)
-
-
-def test_gate_exactly_at_the_floor_passes():
-    import bench
-
-    verdict = bench.gate_verdict(
-        {"vs_baseline": 0.85}, [_window(1.0)], "cpu", 0.85)
-    assert verdict["gate"] == "pass"
-
-
-def test_gate_checks_the_pipelined_pair_too():
-    import bench
-
-    best = _window(1.0, vs_baseline_pipelined=2.0)
-    fresh = {"vs_baseline": 1.0, "vs_baseline_pipelined": 0.5}
-    verdict = bench.gate_verdict(fresh, [best], "cpu", 0.85)
-    assert verdict["gate"] == "fail"
-    assert {r["metric"]: r["ok"] for r in verdict["metrics"]} == {
-        "vs_baseline": True, "vs_baseline_pipelined": False}
-
-
-def test_gate_is_vacuous_without_a_comparable_window():
-    import bench
-
-    # recorded TPU windows never gate a CPU run...
-    verdict = bench.gate_verdict(
-        {"vs_baseline": 0.1}, [_window(1.0, platform="tpu")], "cpu", 0.85)
-    assert verdict["gate"] == "pass" and "vacuous" in verdict["reason"]
-    # ...and labeled side-benches never gate the round record
-    verdict = bench.gate_verdict(
-        {"vs_baseline": 0.1}, [_window(1.0, label="tuned")], "cpu", 0.85)
-    assert verdict["gate"] == "pass" and verdict["metrics"] == []
-
-
-def test_gate_picks_the_best_comparable_window():
-    import bench
-
-    recs = [_window(0.4), _window(1.2), _window(0.9),
-            _window(5.0, platform="tpu")]
-    verdict = bench.gate_verdict({"vs_baseline": 1.0}, recs, "cpu", 0.85)
-    assert verdict["windows_compared"] == 3
-    assert verdict["metrics"][0]["best"] == pytest.approx(1.2)
-    # the BEST window gates, not the latest: 1.0 < 1.2 * 0.85 fails
-    assert verdict["gate"] == "fail"
-    assert verdict["metrics"][0]["floor"] == pytest.approx(1.02)

@@ -577,7 +577,7 @@ def test_daemon_dispatches_groups_largest_cost_first(monkeypatch):
         streams = []
         for tag, sctx in run.streams():
             planner = planning.Planner(
-                sctx.model, spec=sctx.spec, bucketed=True, **plan_opts
+                sctx.model, spec=sctx.spec, **plan_opts
             )
             buckets, order = planner.encode_buckets(sctx)
             streams.append(daemon_mod._Stream(

@@ -69,6 +69,14 @@ def sig(r: dict):
     )
 
 
+def oracle_verdicts(model, hists):
+    """The CPU oracle's ``valid?`` for each history."""
+    return [
+        linear.analysis(model, h0, pure_fs=("read",))["valid?"]
+        for h0 in hists
+    ]
+
+
 # ---------------------------------------------------------------------------
 # determinism: window sizes, bucket splits, chunk boundaries
 # ---------------------------------------------------------------------------
@@ -77,18 +85,11 @@ def sig(r: dict):
 def test_window_and_bucketing_preserve_verdicts_dense_route():
     hists = mixed_corpus()
     model = m.cas_register(0)
-    oracle = [
-        linear.analysis(model, h0, pure_fs=("read",))["valid?"]
-        for h0 in hists
-    ]
+    oracle = oracle_verdicts(model, hists)
     assert True in oracle and False in oracle  # corpus mixes verdicts
-    serial = wgl.check_batch(model, hists, window=1, bucketed=False)
-    assert [o["valid?"] for o in serial] == oracle
-    for window, bucketed in ((1, True), (2, True), (4, True), (4, False)):
-        outs = wgl.check_batch(
-            model, hists, window=window, bucketed=bucketed
-        )
-        assert [o["valid?"] for o in outs] == oracle, (window, bucketed)
+    for window in (1, 2, 4):
+        outs = wgl.check_batch(model, hists, window=window)
+        assert [o["valid?"] for o in outs] == oracle, window
         # device rows stay device rows, the wide row stays an oracle row
         assert outs[-1]["engine"] == "oracle-fallback"
         assert all(o["engine"] == "tpu" for o in outs[:-1])
@@ -100,13 +101,11 @@ def test_window_preserves_verdicts_frontier_route_across_chunks():
     failure events must be identical at every window size."""
     hists = mixed_corpus(seed=7, wide=False)
     model = m.cas_register(0)
-    base = wgl.check_batch(
-        model, hists, max_closure=9, window=1, bucketed=False
-    )
+    base = wgl.check_batch(model, hists, max_closure=9, window=1)
+    assert [o["valid?"] for o in base] == oracle_verdicts(model, hists)
     for window in (1, 4):
         outs = wgl.check_batch(
             model, hists, max_closure=9, max_dispatch=3, window=window,
-            bucketed=True,
         )
         assert [sig(o) for o in outs] == [sig(o) for o in base], window
         assert {o.get("kernel") for o in outs} == {"frontier"}
@@ -121,10 +120,11 @@ def test_escalation_interacts_with_pipelining():
         _gen(rng, n_procs=5, n_ops=30, crash_p=0.02, corrupt=(i % 3 == 0))
         for i in range(9)
     ]
-    base = wgl.check_batch(model, hists, window=1, bucketed=False)
+    base = wgl.check_batch(model, hists, window=1)
+    assert [o["valid?"] for o in base] == oracle_verdicts(model, hists)
     esc = wgl.check_batch(
         model, hists, frontier=2, escalation=(4, 16), max_closure=7,
-        slot_cap=6, max_dispatch=4, window=4, bucketed=True,
+        slot_cap=6, max_dispatch=4, window=4,
     )
     assert [o["valid?"] for o in esc] == [o["valid?"] for o in base]
 
@@ -206,8 +206,8 @@ def test_repeat_runs_identical_under_concurrent_oracle():
     identical pipelined runs produce identical result lists."""
     hists = mixed_corpus(seed=19)
     model = m.cas_register(0)
-    a = wgl.check_batch(model, hists, window=4, bucketed=True)
-    b = wgl.check_batch(model, hists, window=4, bucketed=True)
+    a = wgl.check_batch(model, hists, window=4)
+    b = wgl.check_batch(model, hists, window=4)
     assert [sig(o) for o in a] == [sig(o) for o in b]
 
 
@@ -339,7 +339,7 @@ def test_pipeline_metrics_and_span():
     hists = mixed_corpus(wide=False)
     model = m.cas_register(0)
     obs.enable(reset=True)
-    wgl.check_batch(model, hists, window=4, bucketed=True, max_dispatch=3)
+    wgl.check_batch(model, hists, window=4, max_dispatch=3)
     reg = obs.registry()
     assert (reg.value("jepsen_engine_inflight_depth") or 0) > 1
     assert (reg.value("jepsen_engine_bucket_count") or 0) >= 2
@@ -426,7 +426,7 @@ def test_engine_spans_mirror_onto_the_profiler_trace(tmp_path):
     opts.host_tracer_level = 1
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
-        wgl.check_batch(m.cas_register(0), hists, window=4, bucketed=True,
+        wgl.check_batch(m.cas_register(0), hists, window=4,
                         max_dispatch=3)
     finally:
         jax.profiler.stop_trace()
@@ -465,7 +465,7 @@ def test_window_one_records_serial_depth():
     hists = mixed_corpus(wide=False)
     model = m.cas_register(0)
     obs.enable(reset=True)
-    wgl.check_batch(model, hists, window=1, bucketed=True, max_dispatch=3)
+    wgl.check_batch(model, hists, window=1, max_dispatch=3)
     assert obs.registry().value("jepsen_engine_inflight_depth") == 1
     obs.enable(reset=True)
 
@@ -538,8 +538,6 @@ def test_engine_window_env_default(monkeypatch):
     assert pipeline.default_window() == 7
     monkeypatch.setenv("JEPSEN_TPU_ENGINE_WINDOW", "junk")
     assert pipeline.default_window() == pipeline.DEFAULT_WINDOW
-    monkeypatch.setenv("JEPSEN_TPU_ENGINE_BUCKETED", "0")
-    assert pipeline.default_bucketed() is False
 
 
 def test_cycles_screen_windowed_and_cache_bounded():

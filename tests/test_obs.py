@@ -527,32 +527,6 @@ def test_chunked_first_dispatch_splits_compile_vs_execute():
 
 
 # ---------------------------------------------------------------------------
-# ADVICE r5 regression: bench reporting reads dense's one default
-# ---------------------------------------------------------------------------
-
-
-def test_bench_union_mode_not_rehardcoded(monkeypatch):
-    import bench
-    from jepsen_tpu.ops import dense
-
-    # the headline gate follows dense.DEFAULT_UNION, whatever it is
-    assert bench._headline_config({"dense_union": dense.DEFAULT_UNION})
-    assert not bench._headline_config({"dense_union": "not-a-mode"})
-    # diag reporting resolves through dense._union_mode (env-sensitive)
-    monkeypatch.setenv("JEPSEN_TPU_DENSE_UNION", "gather")
-    assert dense._union_mode() == "gather"
-    assert not bench._headline_config({"dense_union": dense._union_mode()})
-    monkeypatch.delenv("JEPSEN_TPU_DENSE_UNION")
-    assert bench._headline_config({"dense_union": dense._union_mode()})
-    # belt and braces: the default string literal must not be duplicated
-    # in bench.py's reporting/gating sites anymore
-    import inspect
-
-    src = inspect.getsource(bench)
-    assert 'os.environ.get("JEPSEN_TPU_DENSE_UNION"' not in src
-
-
-# ---------------------------------------------------------------------------
 # sliding-window metrics (fleet telemetry)
 # ---------------------------------------------------------------------------
 

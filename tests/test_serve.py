@@ -81,8 +81,7 @@ def test_split_composition_matches_pipeline_run():
 
     ctx = RunContext(model, hists)
     planner = Planner(model, spec=ctx.spec, slot_cap=32,
-                      frontier=wgl.DEFAULT_FRONTIER, max_dispatch=4,
-                      bucketed=True)
+                      frontier=wgl.DEFAULT_FRONTIER, max_dispatch=4)
     ex = Executor(max_dispatch=4)
     buckets, order = planner.encode_buckets(ctx)
     merged, morder = merge_buckets([(buckets, order)])
@@ -108,7 +107,7 @@ def test_merge_buckets_coalesces_across_contexts_and_routes_rows():
     ctx_a = RunContext(model, h_a)
     ctx_b = RunContext(model, h_b)
     planner = Planner(model, spec=ctx_a.spec, slot_cap=32,
-                      frontier=wgl.DEFAULT_FRONTIER, bucketed=True)
+                      frontier=wgl.DEFAULT_FRONTIER)
     runs = [planner.encode_buckets(ctx_a), planner.encode_buckets(ctx_b)]
     merged, order = merge_buckets(runs)
     # same seeds shapes overlap: at least one merged bucket holds rows
@@ -155,7 +154,7 @@ def test_executor_reset_discards_transient_state():
     hists = mixed_corpus(seed=21, n=3, wide=False)
     ctx = RunContext(model, hists)
     planner = Planner(model, spec=ctx.spec, slot_cap=32,
-                      frontier=wgl.DEFAULT_FRONTIER, bucketed=True)
+                      frontier=wgl.DEFAULT_FRONTIER)
     buckets, order = planner.encode_buckets(ctx)
     for k in order:
         pb = planner.plan_rows(k, *buckets[k])
@@ -179,14 +178,13 @@ def test_estimated_cost_hook_orders_kernel_families():
     hists = mixed_corpus(seed=7, n=6, wide=False)
     ctx = RunContext(model, hists)
     planner = Planner(model, spec=ctx.spec, slot_cap=32,
-                      frontier=wgl.DEFAULT_FRONTIER, bucketed=True)
+                      frontier=wgl.DEFAULT_FRONTIER)
     buckets, order = planner.encode_buckets(ctx)
     pbs = [planner.plan_rows(k, *buckets[k]) for k in order]
     assert all(estimated_cost(pb) > 0 for pb in pbs)
     # frontier planning of the same rows costs more than dense
     planner_f = Planner(model, spec=ctx.spec, slot_cap=32,
-                        frontier=wgl.DEFAULT_FRONTIER, max_closure=9,
-                        bucketed=True)
+                        frontier=wgl.DEFAULT_FRONTIER, max_closure=9)
     ctx2 = RunContext(model, hists)
     b2, o2 = planner_f.encode_buckets(ctx2)
     for k in order:
@@ -920,7 +918,7 @@ def test_reset_recovers_from_mid_dispatch_device_fault(
     def run_through(ex):
         ctx = RunContext(model, hists)
         planner = Planner(model, spec=ctx.spec, slot_cap=32,
-                          frontier=wgl.DEFAULT_FRONTIER, bucketed=True)
+                          frontier=wgl.DEFAULT_FRONTIER)
         buckets, order = planner.encode_buckets(ctx)
         for k in order:
             pb = planner.plan_rows(k, *buckets[k])
